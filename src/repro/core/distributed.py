@@ -52,27 +52,9 @@ from __future__ import annotations
 import functools
 from typing import NamedTuple
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# replication checking kwarg was renamed check_rep -> check_vma across jax
-# versions; resolve whichever this jax has (disabled either way: the spike
-# exchange's all_to_all is deliberately unreplicated).
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
 
 from repro.core import engine as E
 from repro.core import hcu as H
@@ -301,11 +283,14 @@ def make_dist_tick(mesh: Mesh, p: BCPNNParams, rc: RouteConfig,
                                    overlap=overlap)
         return be.carry_out(state, p), fired
 
-    fn = shard_map(
+    # check_vma off: the spike exchange's all_to_all is deliberately
+    # unreplicated
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(state_specs, conn_specs, spec_h),
         out_specs=(state_specs, spec_h),
+        check_vma=False,
     )
     # donating the state lets XLA scatter the touched rows/columns in place
     # — the lazy model's bytes-per-tick then match the paper's traffic
@@ -345,11 +330,12 @@ def make_dist_run(mesh: Mesh, p: BCPNNParams, rc: RouteConfig,
         state, fired = jax.lax.scan(body, be.carry_in(state, p), ext)
         return be.carry_out(state, p), fired
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _local_run,
         mesh=mesh,
         in_specs=(state_specs, conn_specs, ext_spec),
         out_specs=(state_specs, fired_spec),
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 

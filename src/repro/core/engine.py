@@ -404,9 +404,9 @@ def worklist_lazy_rows(hcus: H.HCUState, rows, t, p: BCPNNParams,
     n, A = c["n"], c["A"]
     if kb in ("pallas", "pallas_interpret") and fused:
         # megakernel: one scalar-prefetch grid pass over SLOT-ordered
-        # entries (g_row already carries the H*R sentinel on padding slots;
-        # ops reroutes sentinels onto the junk row) updates ij planes AND
-        # i-vectors in place and emits the h-major weight rows directly
+        # entries (g_row already carries the H*R sentinel on padding slots,
+        # which the kernel skips) updates ij planes AND i-vectors in place
+        # and emits the h-major weight rows directly
         W = n * A
         h_of = jnp.arange(W, dtype=jnp.int32) // A
         if lay is not None:
@@ -443,8 +443,8 @@ def worklist_lazy_rows(hcus: H.HCUState, rows, t, p: BCPNNParams,
         order = c["order"]
         h_of = order // A
         # padding entries get the H*R sentinel explicitly (order pads with
-        # 0, which aliases a real row); ops routes sentinels onto the
-        # kernel's junk row so they can never clobber a touched row
+        # 0, which aliases a real row); the kernel skips every entry at or
+        # past nv, so they can never clobber a touched row
         W = order.shape[0]
         if lay is not None:
             planes = tuple(lay.flat_view(f) for f in _ij_flats(hcus))

@@ -25,20 +25,6 @@ from repro.core.params import BCPNNParams
 from repro.core.traces import ZEP, bias, decay_zep, make_coeffs
 from repro.kernels import ops
 
-# jax 0.4.x has no vmap batching rule for optimization_barrier (identity per
-# operand, so the rule is trivial); the sealed compute islands below are
-# used under vmap, so register it when missing.
-try:  # pragma: no cover - exercised only on jax versions lacking the rule
-    from jax._src.lax.lax import optimization_barrier_p as _opt_barrier_p
-    from jax.interpreters import batching as _batching
-
-    if _opt_barrier_p not in _batching.primitive_batchers:
-        def _opt_barrier_batcher(args, dims, **params):
-            return _opt_barrier_p.bind(*args), dims
-        _batching.primitive_batchers[_opt_barrier_p] = _opt_barrier_batcher
-except (ImportError, AttributeError):
-    pass
-
 # Below this many cells the scatter-free write paths (fused where / one-hot
 # reduce) win on XLA CPU's fixed per-scatter cost; above it they would touch
 # O(cells) per tick and break the lazy-traffic property (paper EQ2), so the

@@ -455,9 +455,9 @@ class BlockedLayout:
     # -- Pallas megakernel plumbing (degenerate point only) -----------------
     def flat_view(self, stored: jnp.ndarray) -> jnp.ndarray:
         """Degenerate (Tc == 1) stored plane as the row-padded flat
-        (H*R', C') view — a pure reshape, so the scalar-prefetch megakernel
-        BlockSpecs (kernels/bcpnn_update.py) need no layout variant: only
-        the row indices are remapped (`pad_row_index`)."""
+        (H*R', C') view — a pure reshape, so the scalar-prefetch kernels
+        (kernels/bcpnn_update.py) need no layout variant: only the row
+        indices are remapped (`pad_row_index`)."""
         assert self.tpu_degenerate
         return stored.reshape(stored.shape[0] * self.xr, self.xc)
 
@@ -466,7 +466,7 @@ class BlockedLayout:
 
     def pad_row_index(self, g, n_hcu: int):
         """Canonical flat row index (sentinel n_hcu*R) -> row-padded view
-        index (sentinel n_hcu*R', routed onto the kernels' junk rows)."""
+        index (sentinel n_hcu*R', which the row kernels skip)."""
         rp = self.padded_rows
         return jnp.where(g < n_hcu * self.rows,
                          (g // self.rows) * rp + g % self.rows,

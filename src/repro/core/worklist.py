@@ -40,10 +40,10 @@ consumed by `engine.WorklistBackend`):
     full plane PER ITERATION (measured ~200x at rodent16), which is why the
     writeback is a separate loop rather than folded into the compute loop.
 
-On TPU the same worklist drives the scalar-prefetch Pallas kernel
-(`repro.kernels.bcpnn_update.worklist_update_kernel_call`), whose grid
-iterates worklist entries and DMAs only the touched `(1, C)` row blocks,
-aliased in place. `repro.core.engine` orchestrates both (size-guarded like
+On TPU the same worklist drives the scalar-prefetch Pallas kernels
+(`repro.kernels.bcpnn_update.fused_row_update_kernel_call` and
+`worklist_update_kernel_call`), whose grids iterate worklist entries and
+DMA only the touched plane rows, in place. `repro.core.engine` orchestrates both (size-guarded like
 `hcu.DENSE_CELLS_MAX`, see `hcu.use_worklist`); this module holds the
 backend-independent loop primitives.
 """

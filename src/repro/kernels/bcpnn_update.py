@@ -16,14 +16,14 @@ Five entry points:
   row_update_kernel_call        : (S, C) row blocks, rank-1 counts x zj
   col_update_kernel_call        : a column viewed as (R/128, 128) lanes
   worklist_update_kernel_call   : scalar-prefetch grid over a network-global
-                                  worklist of flat (H*R, C) plane rows
+                                  worklist of flat (H*R, Cp) plane rows
   fused_row_update_kernel_call  : the worklist row-phase MEGAKERNEL — same
                                   scalar-prefetch grid, but one grid step
                                   completes the whole row phase for its
                                   entry: the five ij planes AND the four
-                                  i-vector planes are aliased in place, and
-                                  the freshly recomputed weight row is
-                                  emitted per entry for the WTA drive
+                                  i-vectors are rewritten in place, and the
+                                  freshly recomputed weight row is emitted
+                                  per entry for the WTA drive
   fused_col_update_kernel_call  : the worklist column-phase MEGAKERNEL —
                                   2-D scalar-prefetch grid over FIRED
                                   ENTRIES x ROW-BLOCKS: each step rewrites
@@ -45,26 +45,36 @@ them at its TPU degenerate point (Tc == 1, the (8, 128) tile) as a pure
 reshape (`BlockedLayout.flat_view`) with the row-index stream remapped by
 the engine — no BlockSpec/index-map variant needed here.
 
-The worklist kernel is the TPU half of the O(touched rows) tick runtime
-(`repro.core.worklist` + `repro.core.engine.WorklistBackend`; the flat
-(H*R, C) planes it consumes are the canonical STORED layout of
-`NetworkState.hcus` since the TickEngine refactor): the deduplicated
-worklist row indices arrive as a scalar-prefetch operand, every BlockSpec
-index_map is driven by them, and
-each grid step DMAs exactly one touched (1, C) row block per plane, updates
-it with the fused cell math, and writes it back in place. Per tick the
-planes therefore cost O(worklist) row-block DMAs instead of O(H*R*C)
-gather/scatter traffic — the memory-access shape of the paper's lazy model
-(§VI.D: bandwidth scales with spikes, not synapses). Grid steps past the
-valid-entry count (and steps whose entry was deduplicated away) write their
-block back unchanged. Because grid steps write data-dependent, potentially
-repeated rows in place, the worklist grid is declared with
-``("arbitrary",)`` dimension semantics — never "parallel", which is
-reserved for the dense row/col kernels whose blocks are disjoint.
+The two worklist ROW kernels are the TPU half of the O(touched rows) tick
+runtime (`repro.core.worklist` + `repro.core.engine.WorklistBackend`; the
+flat (H*R, C) planes they consume are the canonical STORED layout of
+`NetworkState.hcus`). Mosaic takes no (1, C) row block and no (1, 1)
+scalar block, so they work as follows:
+
+  * the planes (and the fused kernel's i-vectors, viewed as (HR/128, 128))
+    stay in HBM (`memory_space=pl.ANY`); grid step i copies the touched
+    row rows[i] into VMEM scratch, updates it with the fused cell math and
+    copies it back, with synchronous DMAs on the aliased output buffers.
+    Two entries in one (8, 128) tile — which the worklist's slot order
+    does not keep adjacent — therefore never see a stale copy of it;
+  * the worklist row indices, the valid count and `now` arrive as
+    scalar-prefetch operands, the per-entry scalars (counts, P_i, the new
+    i-vector values) as whole arrays in SMEM, and the per-entry Zj/Pj rows
+    as (8, Cp) VMEM blocks from which each step picks its sublane;
+  * invalid steps (past the valid count, or a sentinel row) touch no
+    plane.
+
+Per tick the planes therefore cost O(worklist) row DMAs instead of
+O(H*R*C) gather/scatter traffic — the memory-access shape of the paper's
+lazy model (§VI.D: bandwidth scales with spikes, not synapses). Because
+grid steps rewrite data-dependent rows in place, the worklist grids are
+declared with ``("arbitrary", ...)`` dimension semantics — never
+"parallel", which is reserved for the dense row/col kernels whose blocks
+are disjoint.
 
 Validated against `bcpnn_ref` in interpret mode (tests/test_kernels.py,
-tests/test_worklist.py); on a real TPU the same code path compiles to
-Mosaic.
+tests/test_worklist.py) and compiled for a described TPU v5e at rodent
+and human widths (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -73,11 +83,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # compiler params API varies across jax versions; best-effort only
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.traces import DecayCoeffs
 
@@ -137,25 +143,16 @@ def _col_kernel(now_ref, z_ref, e_ref, p_ref, w_ref, t_ref, zi_ref, pi_ref,
 
 
 def _compiler_params(semantics=("parallel", "parallel")):
-    """Best-effort TPU compiler params with explicit dimension semantics.
+    """TPU compiler params with explicit dimension semantics.
 
     The dense row/col kernels write disjoint (bs, bl) blocks, so their 2-D
-    grids are genuinely ("parallel", "parallel"). The worklist kernel's grid
-    is data-dependent — prefetched row indices may repeat (padding entries
-    all alias one row) and every block is rewritten in place — so it MUST be
-    ("arbitrary",): declaring it parallel would license Mosaic to reorder or
-    overlap grid steps whose writes alias.
+    grids are genuinely ("parallel", "parallel"). The worklist kernels'
+    grids are data-dependent — steps rewrite planes in place at prefetched
+    rows, and one step must see the writes of the steps before it — so they
+    MUST be ("arbitrary", ...): declaring them parallel would license
+    Mosaic to reorder or overlap grid steps whose writes alias.
     """
-    if pltpu is None:
-        return None
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=tuple(semantics))
-            except Exception:  # pragma: no cover
-                return None
-    return None
+    return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 # Alias the five state planes onto the five outputs: Zij/Eij/Pij/Wij/Tij are
@@ -183,10 +180,6 @@ def row_update_kernel_call(zij, eij, pij, wij, tij, now, counts, zj, p_i, p_j,
     one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     out_shape = [jax.ShapeDtypeStruct((S, C), jnp.float32)] * 4 \
         + [jax.ShapeDtypeStruct((S, C), jnp.int32)]
-    kwargs = {}
-    cp = _compiler_params()
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     fn = pl.pallas_call(
         functools.partial(_row_kernel, k=k, eps=eps),
         grid=grid,
@@ -194,141 +187,190 @@ def row_update_kernel_call(zij, eij, pij, wij, tij, now, counts, zj, p_i, p_j,
         out_specs=[sc, sc, sc, sc, sc],
         out_shape=out_shape,
         input_output_aliases=_PLANE_ALIASES,
+        compiler_params=_compiler_params(),
         interpret=interpret,
-        **kwargs,
     )
     return fn(now_arr, zij, eij, pij, wij, tij,
               counts.reshape(S, 1), zj.reshape(1, C),
               p_i.reshape(S, 1), p_j.reshape(1, C))
 
 
-def _worklist_kernel(rows_ref, nv_ref, now_ref, z_ref, e_ref, p_ref, w_ref,
-                     t_ref, counts_ref, zj_ref, pi_ref, pj_ref,
-                     zo_ref, eo_ref, po_ref, wo_ref, to_ref,
+def _dma(pairs, sem):
+    """Start one DMA per (src, dst) ref pair, then wait for all of them.
+
+    The worklist kernels move every plane row through VMEM with these
+    synchronous copies: a step's write-back has landed in HBM before the
+    next step reads, so two entries that share an (8, 128) tile — or an
+    i-vector lane group — never compute from or write back a stale copy,
+    whatever their order in the worklist."""
+    copies = [pltpu.make_async_copy(s, d, sem.at[n])
+              for n, (s, d) in enumerate(pairs)]
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
+
+
+def _update_row_bufs(bz, be, bp, bw, bt, now, count, zj, p_i, pj,
+                     k: DecayCoeffs, eps: float):
+    """Fused cell math on one (1, Cp) plane row held in VMEM scratch:
+    the staged Zij/Eij/Pij/Tij rows are rewritten in place, Wij recomputed
+    into bw. Returns the new weight row."""
+    dt = (now - bt[...]).astype(jnp.float32)
+    z1, e1, p1, w1 = _cell_math(bz[...], be[...], bp[...], dt, count * zj,
+                                p_i, pj, k, eps)
+    bz[...] = z1
+    be[...] = e1
+    bp[...] = p1
+    bw[...] = w1
+    bt[...] = jnp.full_like(bt[...], now)
+    return w1
+
+
+def _row_scratch(cp: int):
+    """VMEM staging rows for the five ij planes (Tij is int32)."""
+    return [pltpu.VMEM((1, cp), jnp.float32)] * 4 \
+        + [pltpu.VMEM((1, cp), jnp.int32)]
+
+
+def _worklist_kernel(rows_ref, nv_ref, now_ref, counts_ref, pi_ref,
+                     z_hbm, e_hbm, p_hbm, w_hbm, t_hbm, zj_ref, pj_ref,
+                     zo, eo, po, wo, to, bz, be, bp, bw, bt, sem,
                      *, k: DecayCoeffs, eps: float):
-    """One worklist entry per grid step: the (1, C) row block the BlockSpec
-    index_maps DMA'd in (rows_ref[i] selected it) is updated with the fused
-    cell math and written back in place. Entries at or past nv pass their
-    block through unchanged; the caller (ops.worklist_row_update) reroutes
-    them onto a junk row past the logical plane, so a padding step can
-    never even revisit a touched row — the `valid` gate here is defense in
-    depth on top of that, under the ("arbitrary",) sequential grid
-    semantics."""
+    """One worklist entry per grid step. The planes stay in HBM: entry i
+    (i < nv) DMAs its plane row rows_ref[i] into VMEM, updates it with the
+    fused cell math and DMAs it back; entries at or past nv do nothing.
+    Rows are read through the aliased OUTPUT refs (the same HBM buffers as
+    the inputs), so every step sees the writes of the steps before it.
+    Per-entry scalars come from SMEM; the per-entry Zj/Pj rows arrive as
+    (8, Cp) blocks and the entry picks its sublane."""
+    del z_hbm, e_hbm, p_hbm, w_hbm, t_hbm          # aliased onto zo..to
     i = pl.program_id(0)
-    valid = i < nv_ref[0]
-    now = now_ref[0, 0]
-    dt = (now - t_ref[...]).astype(jnp.float32)
-    dz = counts_ref[...] * zj_ref[...]           # (1,1) * (1,BL) rank-1
-    z1, e1, p1, w1 = _cell_math(z_ref[...], e_ref[...], p_ref[...], dt, dz,
-                                pi_ref[...], pj_ref[...], k, eps)
-    zo_ref[...] = jnp.where(valid, z1, z_ref[...])
-    eo_ref[...] = jnp.where(valid, e1, e_ref[...])
-    po_ref[...] = jnp.where(valid, p1, p_ref[...])
-    wo_ref[...] = jnp.where(valid, w1, w_ref[...])
-    to_ref[...] = jnp.where(valid, jnp.full_like(t_ref[...], now), t_ref[...])
+
+    @pl.when(i < nv_ref[0])
+    def _():
+        row = pl.ds(rows_ref[i], 1)
+        _dma([(zo.at[row], bz), (eo.at[row], be), (po.at[row], bp),
+              (to.at[row], bt)], sem)
+        s = pl.ds(i % 8, 1)
+        _update_row_bufs(bz, be, bp, bw, bt, now_ref[0], counts_ref[i],
+                         zj_ref[s, :], pi_ref[i], pj_ref[s, :], k, eps)
+        _dma([(bz, zo.at[row]), (be, eo.at[row]), (bp, po.at[row]),
+              (bw, wo.at[row]), (bt, to.at[row])], sem)
 
 
 # With PrefetchScalarGridSpec the alias indices count the scalar-prefetch
-# operands first: 0=rows, 1=nv, then 2=now, 3=zij ... 7=tij.
-_WORKLIST_ALIASES = {3: 0, 4: 1, 5: 2, 6: 3, 7: 4}
+# operands first: 0=rows, 1=nv, 2=now, 3=counts, 4=p_i, then 5=zij ... 9=tij.
+_WORKLIST_ALIASES = {5: 0, 6: 1, 7: 2, 8: 3, 9: 4}
+
+
+def _smem():
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _hbm():
+    return pl.BlockSpec(memory_space=pl.ANY)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "eps", "interpret"))
 def worklist_update_kernel_call(zij, eij, pij, wij, tij, rows, nv, now,
                                 counts, zj, p_i, pj, k: DecayCoeffs,
                                 eps: float, interpret: bool = False):
-    """Scalar-prefetch Pallas worklist update over flat (HR, C) planes.
+    """Scalar-prefetch Pallas worklist update over flat (HR, Cp) planes.
 
     rows (W,) int32 — flat plane row index per worklist entry, compacted
-    valid-first and clipped into range (entries >= nv are ignored);
-    nv (1,) int32 — valid-entry count; counts/p_i (W,) and zj/pj (W, C) —
-    per-entry operands. HR % 8 == 0 and C % 128 == 0 required (ops.py pads).
-    The five plane inputs alias the outputs: each grid step rewrites only
-    its touched (1, C) row block in place — O(worklist) DMA per call.
+    valid-first and in [0, HR) for entries < nv (entries >= nv are skipped
+    whatever they hold); nv (1,) int32 — valid-entry count; counts/p_i (W,)
+    per-entry scalars (SMEM); zj/pj (W, Cp) per-entry rows. Cp % 128 == 0
+    and W % 8 == 0 required (ops.py pads). The five plane inputs alias the
+    outputs and stay in HBM: each valid step rewrites only its touched row —
+    O(worklist) DMA per call.
     """
-    HR, C = zij.shape
+    HR, Cp = zij.shape
     W = rows.shape[0]
-    if pltpu is None:  # pragma: no cover - pltpu import failed
-        raise NotImplementedError(
-            "worklist_update_kernel_call needs jax.experimental.pallas.tpu "
-            "(PrefetchScalarGridSpec); use the 'ref' worklist path instead")
-    now_arr = jnp.asarray(now, jnp.int32).reshape(1, 1)
-    row_spec = pl.BlockSpec((1, C), lambda i, rows_ref, nv_ref:
-                            (rows_ref[i], 0))
-    ent_spec = pl.BlockSpec((1, C), lambda i, rows_ref, nv_ref: (i, 0))
-    ent1_spec = pl.BlockSpec((1, 1), lambda i, rows_ref, nv_ref: (i, 0))
-    one = pl.BlockSpec((1, 1), lambda i, rows_ref, nv_ref: (0, 0))
+    ent = pl.BlockSpec((8, Cp), lambda i, *_: (i // 8, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(W,),
-        in_specs=[one, row_spec, row_spec, row_spec, row_spec, row_spec,
-                  ent1_spec, ent_spec, ent1_spec, ent_spec],
-        out_specs=[row_spec] * 5,
+        in_specs=[_smem(), _smem()] + [_hbm()] * 5 + [ent, ent],
+        out_specs=[_hbm()] * 5,
+        scratch_shapes=_row_scratch(Cp) + [pltpu.SemaphoreType.DMA((5,))],
     )
-    out_shape = [jax.ShapeDtypeStruct((HR, C), jnp.float32)] * 4 \
-        + [jax.ShapeDtypeStruct((HR, C), jnp.int32)]
-    kwargs = {}
-    cp = _compiler_params(("arbitrary",))
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
+    out_shape = [jax.ShapeDtypeStruct((HR, Cp), jnp.float32)] * 4 \
+        + [jax.ShapeDtypeStruct((HR, Cp), jnp.int32)]
     fn = pl.pallas_call(
         functools.partial(_worklist_kernel, k=k, eps=eps),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=_WORKLIST_ALIASES,
+        compiler_params=_compiler_params(("arbitrary",)),
         interpret=interpret,
-        **kwargs,
     )
     return fn(rows.astype(jnp.int32), jnp.asarray(nv, jnp.int32).reshape(1),
-              now_arr, zij, eij, pij, wij, tij,
-              counts.reshape(W, 1), zj, p_i.reshape(W, 1), pj)
+              jnp.asarray(now, jnp.int32).reshape(1),
+              counts, p_i, zij, eij, pij, wij, tij, zj, pj)
 
 
-def _fused_row_kernel(rows_ref, now_ref, z_ref, e_ref, p_ref, w_ref, t_ref,
-                      zi_ref, ei_ref, pi_ref, ti_ref, counts_ref, zj_ref,
-                      piv_ref, pj_ref, zin_ref, ein_ref, pin_ref,
-                      zo_ref, eo_ref, po_ref, wo_ref, to_ref,
-                      zio_ref, eio_ref, pio_ref, tio_ref, wrow_ref,
+def _fused_row_kernel(rows_ref, now_ref, counts_ref, piv_ref, zin_ref,
+                      ein_ref, pin_ref, z_hbm, e_hbm, p_hbm, w_hbm, t_hbm,
+                      zi_hbm, ei_hbm, pi_hbm, ti_hbm, zj_ref, pj_ref,
+                      zo, eo, po, wo, to, zio, eio, pio, tio, wrow_ref,
+                      bz, be, bp, bw, bt, bzi, bei, bpi, bti, sem,
                       *, k: DecayCoeffs, eps: float, hr: int):
     """One worklist entry per grid step, the WHOLE row phase fused:
 
-      * the (1, C) ij-plane row blocks the index_maps DMA'd in are updated
-        with the fused cell math and written back in place (aliased);
-      * the entry's (1, 1) i-vector cells are rewritten in place from the
-        prefetched post-decay values (the i-vector math runs once in the
+      * the entry's ij-plane rows are DMA'd from HBM, updated with the
+        fused cell math and DMA'd back in place (aliased);
+      * the entry's i-vector cells are rewritten in place from the
+        post-decay values in SMEM (the i-vector math runs once in the
         engine prologue — same sealed `ivec_decay` island as every other
-        path — so the kernel only moves the results);
-      * the recomputed weight row is emitted to the per-entry `wrow` output,
-        which IS the WTA drive input — no post-kernel re-gather of Wij.
+        path — so the kernel only moves the results). The i-vectors are
+        viewed as (HR/128, 128): the cell is lane r % 128 of row r // 128,
+        which is read, patched under a lane mask and written back;
+      * the recomputed weight row is emitted to the per-entry `wrow`
+        output, which IS the WTA drive input — no post-kernel re-gather of
+        Wij.
 
     Validity is per entry, not a compacted prefix: `rows` is slot-ordered
-    and the caller reroutes invalid slots onto the junk row past the logical
-    plane (row >= hr), so a padding step can only ever rewrite junk. The
-    `valid` gate keeps even that write a pass-through."""
+    and the caller marks invalid slots with a row >= hr. Such a step
+    touches no plane and emits a zero weight row."""
+    del z_hbm, e_hbm, p_hbm, w_hbm, t_hbm, zi_hbm, ei_hbm, pi_hbm, ti_hbm
     i = pl.program_id(0)
-    valid = rows_ref[i] < hr
-    now = now_ref[0, 0]
-    dt = (now - t_ref[...]).astype(jnp.float32)
-    dz = counts_ref[...] * zj_ref[...]           # (1,1) * (1,BL) rank-1
-    z1, e1, p1, w1 = _cell_math(z_ref[...], e_ref[...], p_ref[...], dt, dz,
-                                piv_ref[...], pj_ref[...], k, eps)
-    zo_ref[...] = jnp.where(valid, z1, z_ref[...])
-    eo_ref[...] = jnp.where(valid, e1, e_ref[...])
-    po_ref[...] = jnp.where(valid, p1, p_ref[...])
-    wo_ref[...] = jnp.where(valid, w1, w_ref[...])
-    to_ref[...] = jnp.where(valid, jnp.full_like(t_ref[...], now), t_ref[...])
-    zio_ref[...] = jnp.where(valid, zin_ref[...], zi_ref[...])
-    eio_ref[...] = jnp.where(valid, ein_ref[...], ei_ref[...])
-    pio_ref[...] = jnp.where(valid, pin_ref[...], pi_ref[...])
-    tio_ref[...] = jnp.where(valid, jnp.full_like(ti_ref[...], now),
-                             ti_ref[...])
-    wrow_ref[...] = jnp.where(valid, w1, jnp.zeros_like(w1))
+    r = rows_ref[i]
+    valid = r < hr
+    s = pl.ds(i % 8, 1)
+
+    @pl.when(valid)
+    def _():
+        now = now_ref[0]
+        row, ivrow = pl.ds(r, 1), pl.ds(r // 128, 1)
+        _dma([(zo.at[row], bz), (eo.at[row], be), (po.at[row], bp),
+              (to.at[row], bt), (zio.at[ivrow], bzi), (eio.at[ivrow], bei),
+              (pio.at[ivrow], bpi), (tio.at[ivrow], bti)], sem)
+        w1 = _update_row_bufs(bz, be, bp, bw, bt, now, counts_ref[i],
+                              zj_ref[s, :], piv_ref[i], pj_ref[s, :], k, eps)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1) == r % 128
+        bzi[...] = jnp.where(lane, zin_ref[i], bzi[...])
+        bei[...] = jnp.where(lane, ein_ref[i], bei[...])
+        bpi[...] = jnp.where(lane, pin_ref[i], bpi[...])
+        bti[...] = jnp.where(lane, now, bti[...])
+        _dma([(bz, zo.at[row]), (be, eo.at[row]), (bp, po.at[row]),
+              (bw, wo.at[row]), (bt, to.at[row]), (bzi, zio.at[ivrow]),
+              (bei, eio.at[ivrow]), (bpi, pio.at[ivrow]),
+              (bti, tio.at[ivrow])], sem)
+        wrow_ref[s, :] = w1
+
+    @pl.when(jnp.logical_not(valid))
+    def _():
+        wrow_ref[s, :] = jnp.zeros((1, wrow_ref.shape[1]), jnp.float32)
 
 
 # Megakernel aliases (prefetch operands count first): 0=rows, 1=now,
-# 2=zij..6=tij -> plane outputs 0..4; 7=zi..10=ti -> i-vector outputs 5..8.
-# Output 9 (the per-entry weight row) is the one fresh allocation.
-_FUSED_ALIASES = {2: 0, 3: 1, 4: 2, 5: 3, 6: 4, 7: 5, 8: 6, 9: 7, 10: 8}
+# 2..6 = the SMEM per-entry scalars, 7=zij..11=tij -> plane outputs 0..4;
+# 12=zi..15=ti -> i-vector outputs 5..8. Output 9 (the per-entry weight
+# row) is the one fresh allocation.
+_FUSED_ALIASES = {7: 0, 8: 1, 9: 2, 10: 3, 11: 4, 12: 5, 13: 6, 14: 7,
+                  15: 8}
 
 
 @functools.partial(jax.jit, static_argnames=("k", "eps", "hr", "interpret"))
@@ -338,59 +380,44 @@ def fused_row_update_kernel_call(zij, eij, pij, wij, tij, zi, ei, pi, ti,
                                  eps: float, hr: int, interpret: bool = False):
     """Scalar-prefetch Pallas megakernel for the fused worklist row phase.
 
-    Planes (HRp, C) f32/int32, i-vectors (HRp, 1); rows (W,) int32 SLOT-
-    ordered flat row indices — entries for padding/duplicate slots must be
-    rerouted by the caller onto junk rows in [hr, HRp) (``hr`` is the
-    logical H*R row count; everything at or past it is junk territory).
-    counts/p_i/zi_new/ei_new/pi_new (W, 1) and zj/pj (W, C) are per-entry
-    operands. The nine state-plane inputs alias the nine state outputs
-    (in-place rewrite); the tenth output is the (W, C) weight-row buffer
-    consumed by the WTA drive. HRp % 8 == 0 and C % 128 == 0 required
-    (ops.py pads).
+    Planes (HR, Cp) f32/int32 with Cp % 128 == 0; i-vectors viewed as
+    (HRq, 128) with HRq * 128 >= hr; rows (W,) int32 SLOT-ordered flat row
+    indices, >= hr on padding/duplicate slots (``hr`` is the logical H*R
+    row count). counts/p_i/zi_new/ei_new/pi_new (W,) per-entry scalars
+    (SMEM) and zj/pj (W, Cp) per-entry rows, W % 8 == 0 (ops.py pads). The
+    nine state inputs alias the nine state outputs and stay in HBM
+    (in-place rewrite); the tenth output is the (W, Cp) weight-row buffer
+    consumed by the WTA drive.
     """
-    HR, C = zij.shape
+    HR, Cp = zij.shape
+    HRq = zi.shape[0]
     W = rows.shape[0]
-    if pltpu is None:  # pragma: no cover - pltpu import failed
-        raise NotImplementedError(
-            "fused_row_update_kernel_call needs jax.experimental.pallas.tpu "
-            "(PrefetchScalarGridSpec); use the 'ref' fused loop instead")
-    now_arr = jnp.asarray(now, jnp.int32).reshape(1, 1)
-    row_spec = pl.BlockSpec((1, C), lambda i, rows_ref: (rows_ref[i], 0))
-    iv_spec = pl.BlockSpec((1, 1), lambda i, rows_ref: (rows_ref[i], 0))
-    ent_spec = pl.BlockSpec((1, C), lambda i, rows_ref: (i, 0))
-    ent1_spec = pl.BlockSpec((1, 1), lambda i, rows_ref: (i, 0))
-    one = pl.BlockSpec((1, 1), lambda i, rows_ref: (0, 0))
+    ent = pl.BlockSpec((8, Cp), lambda i, *_: (i // 8, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(W,),
-        in_specs=[one,
-                  row_spec, row_spec, row_spec, row_spec, row_spec,
-                  iv_spec, iv_spec, iv_spec, iv_spec,
-                  ent1_spec, ent_spec, ent1_spec, ent_spec,
-                  ent1_spec, ent1_spec, ent1_spec],
-        out_specs=[row_spec] * 5 + [iv_spec] * 4 + [ent_spec],
+        in_specs=[_smem()] * 5 + [_hbm()] * 9 + [ent, ent],
+        out_specs=[_hbm()] * 9 + [ent],
+        scratch_shapes=_row_scratch(Cp)
+        + [pltpu.VMEM((1, 128), jnp.float32)] * 3
+        + [pltpu.VMEM((1, 128), jnp.int32), pltpu.SemaphoreType.DMA((9,))],
     )
-    out_shape = [jax.ShapeDtypeStruct((HR, C), jnp.float32)] * 4 \
-        + [jax.ShapeDtypeStruct((HR, C), jnp.int32)] \
-        + [jax.ShapeDtypeStruct((HR, 1), jnp.float32)] * 3 \
-        + [jax.ShapeDtypeStruct((HR, 1), jnp.int32)] \
-        + [jax.ShapeDtypeStruct((W, C), jnp.float32)]
-    kwargs = {}
-    cp = _compiler_params(("arbitrary",))
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
+    out_shape = [jax.ShapeDtypeStruct((HR, Cp), jnp.float32)] * 4 \
+        + [jax.ShapeDtypeStruct((HR, Cp), jnp.int32)] \
+        + [jax.ShapeDtypeStruct((HRq, 128), jnp.float32)] * 3 \
+        + [jax.ShapeDtypeStruct((HRq, 128), jnp.int32)] \
+        + [jax.ShapeDtypeStruct((W, Cp), jnp.float32)]
     fn = pl.pallas_call(
         functools.partial(_fused_row_kernel, k=k, eps=eps, hr=hr),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=_FUSED_ALIASES,
+        compiler_params=_compiler_params(("arbitrary",)),
         interpret=interpret,
-        **kwargs,
     )
-    return fn(rows.astype(jnp.int32), now_arr, zij, eij, pij, wij, tij,
-              zi, ei, pi, ti, counts.reshape(W, 1), zj,
-              p_i.reshape(W, 1), pj, zi_new.reshape(W, 1),
-              ei_new.reshape(W, 1), pi_new.reshape(W, 1))
+    return fn(rows.astype(jnp.int32), jnp.asarray(now, jnp.int32).reshape(1),
+              counts, p_i, zi_new, ei_new, pi_new,
+              zij, eij, pij, wij, tij, zi, ei, pi, ti, zj, pj)
 
 
 def _fused_col_kernel(rbase_ref, rstep_ref, jt_ref, jl_ref, now_ref, z_ref,
@@ -410,7 +437,8 @@ def _fused_col_kernel(rbase_ref, rstep_ref, jt_ref, jl_ref, now_ref, z_ref,
 
     The per-entry presynaptic traces arrive as (bs, kp) tiles of the
     lane-padded (R, kp) buffers; the entry's own lane is selected with a
-    second iota mask and a lane reduce. Validity arrives as
+    second iota mask and a lane reduce. The postsynaptic P scalar comes
+    from SMEM. Validity arrives as
     rstep_ref[e] (1 = valid): the caller pins every one of a padding
     entry's grid steps onto the dedicated junk row-block past the logical
     plane (rbase = HR/bs, rstep = 0), so a padding step can only ever
@@ -423,7 +451,7 @@ def _fused_col_kernel(rbase_ref, rstep_ref, jt_ref, jl_ref, now_ref, z_ref,
     e = pl.program_id(0)
     valid = rstep_ref[e] == 1
     jl = jl_ref[e]
-    now = now_ref[0, 0]
+    now = now_ref[0]
     lane = jax.lax.broadcasted_iota(jnp.int32, (bs, bl), 1)
     hit = valid & (lane == jl)                              # (bs, bl) mask
     # select the entry's presynaptic lane out of the (bs, kp) trace tiles
@@ -433,7 +461,7 @@ def _fused_col_kernel(rbase_ref, rstep_ref, jt_ref, jl_ref, now_ref, z_ref,
     p_i = jnp.sum(pi_ref[...] * sel, axis=1, keepdims=True)
     dt = (now - t_ref[...]).astype(jnp.float32)
     z1, e1, p1, w1 = _cell_math(z_ref[...], e_ref[...], p_ref[...], dt,
-                                zi, p_i, pj_ref[...], k, eps)
+                                zi, p_i, pj_ref[e], k, eps)
     zo_ref[...] = jnp.where(hit, z1, z_ref[...])
     eo_ref[...] = jnp.where(hit, e1, e_ref[...])
     po_ref[...] = jnp.where(hit, p1, p_ref[...])
@@ -467,8 +495,8 @@ def fused_col_update_kernel_call(zij, eij, pij, wij, tij, row_base, row_step,
     regardless of R (a human-scale R=10000 column does NOT fit VMEM as one
     block). zi_cols/pi_cols (r, kp) are the per-entry presynaptic traces
     at `now`, column-major and lane-padded to kp == 128 so their blocks
-    cover the lane dimension exactly; pj_e (K, 1) the per-entry
-    postsynaptic P scalar. The five plane inputs alias the five outputs:
+    cover the lane dimension exactly; pj_e (K,) the per-entry postsynaptic
+    P scalar (SMEM). The five plane inputs alias the five outputs:
     each grid step rewrites one (bs, 128) tile of the fired column in
     place — O(fired columns x R/bs) tile DMAs per call, the minimum the
     128-lane tile granularity allows (the paper's §VI.D column budget, at
@@ -480,42 +508,30 @@ def fused_col_update_kernel_call(zij, eij, pij, wij, tij, row_base, row_step,
     K = row_base.shape[0]
     R_BS = r // bs
     kp = zi_cols.shape[1]
-    if pltpu is None:  # pragma: no cover - pltpu import failed
-        raise NotImplementedError(
-            "fused_col_update_kernel_call needs jax.experimental.pallas.tpu "
-            "(PrefetchScalarGridSpec); use the 'ref' fused loop instead")
-    now_arr = jnp.asarray(now, jnp.int32).reshape(1, 1)
     tile = pl.BlockSpec((bs, DEFAULT_BLOCK_L),
-                        lambda e, rb, rbase, rstep, jt, jl:
+                        lambda e, rb, rbase, rstep, jt, jl, now:
                         (rbase[e] + rb * rstep[e], jt[e]))
-    ent_tile = pl.BlockSpec((bs, kp),
-                            lambda e, rb, rbase, rstep, jt, jl: (rb, 0))
-    ent1 = pl.BlockSpec((1, 1), lambda e, rb, rbase, rstep, jt, jl: (e, 0))
-    one = pl.BlockSpec((1, 1), lambda e, rb, rbase, rstep, jt, jl: (0, 0))
+    ent_tile = pl.BlockSpec((bs, kp), lambda e, rb, *_: (rb, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(K, R_BS),
-        in_specs=[one, tile, tile, tile, tile, tile,
-                  ent_tile, ent_tile, ent1],
+        in_specs=[tile] * 5 + [ent_tile, ent_tile, _smem()],
         out_specs=[tile] * 5,
     )
     out_shape = [jax.ShapeDtypeStruct((HRp, Cp), jnp.float32)] * 4 \
         + [jax.ShapeDtypeStruct((HRp, Cp), jnp.int32)]
-    kwargs = {}
-    cp = _compiler_params(("arbitrary", "arbitrary"))
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     fn = pl.pallas_call(
         functools.partial(_fused_col_kernel, k=k, eps=eps, bs=bs,
                           bl=DEFAULT_BLOCK_L, kp=kp),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=_FUSED_COL_ALIASES,
+        compiler_params=_compiler_params(("arbitrary", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )
     return fn(row_base.astype(jnp.int32), row_step.astype(jnp.int32),
-              j_tile.astype(jnp.int32), j_lane.astype(jnp.int32), now_arr,
+              j_tile.astype(jnp.int32), j_lane.astype(jnp.int32),
+              jnp.asarray(now, jnp.int32).reshape(1),
               zij, eij, pij, wij, tij, zi_cols, pi_cols, pj_e)
 
 
@@ -533,10 +549,6 @@ def col_update_kernel_call(zij, eij, pij, wij, tij, now, zi_t, p_i, p_j_scalar,
     one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     out_shape = [jax.ShapeDtypeStruct((S, C), jnp.float32)] * 4 \
         + [jax.ShapeDtypeStruct((S, C), jnp.int32)]
-    kwargs = {}
-    cp = _compiler_params()
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     fn = pl.pallas_call(
         functools.partial(_col_kernel, k=k, eps=eps),
         grid=grid,
@@ -544,8 +556,8 @@ def col_update_kernel_call(zij, eij, pij, wij, tij, now, zi_t, p_i, p_j_scalar,
         out_specs=[sc, sc, sc, sc, sc],
         out_shape=out_shape,
         input_output_aliases=_PLANE_ALIASES,
+        compiler_params=_compiler_params(),
         interpret=interpret,
-        **kwargs,
     )
     return fn(now_arr, zij, eij, pij, wij, tij, zi_t, p_i,
               jnp.asarray(p_j_scalar, jnp.float32).reshape(1, 1))

@@ -20,12 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -121,9 +116,7 @@ def flash_attention(q, k, v, *, scale: float, causal: bool = True,
 
 
 def _new_scratch(shape, dtype):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemorySpace.ANY(shape, dtype)  # pragma: no cover
+    return pltpu.VMEM(shape, dtype)
 
 
 def flash_attention_ref(q, k, v, *, scale: float, causal: bool = True,

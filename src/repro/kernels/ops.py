@@ -80,39 +80,35 @@ def worklist_row_update(zij, eij, pij, wij, tij, rows, nv, now, counts, zj,
     """Worklist row update over the canonical flat (H*R, C) planes (Pallas
     backends only; the "ref" worklist path lives in `repro.core.worklist` as
     in-place dynamic-slice loops — this wrapper is the TPU/interpret
-    dispatch). Since PR 3 the flat planes are `NetworkState.hcus`'s STORED
-    layout (`core.layout.flat_state`), so the engine passes them here
-    directly — no flatten/unflatten around the call.
+    dispatch). The flat planes are `NetworkState.hcus`'s STORED layout
+    (`core.layout.flat_state`), so the engine passes them here directly.
 
     rows (W,): compacted-valid-first flat row indices (entries >= nv are
-    ignored whatever they hold); counts/p_i (W,); zj/pj (W, C) per-entry
-    operands. Planes are padded to HR+>=1 junk rows (8-multiple) and a lane
-    multiple of C; every entry at or past nv is rerouted onto the junk
-    region so a padding grid step can never revisit (and, in interpret
-    mode, clobber) a row a valid entry updated. The alignment padding is the
-    one remaining per-call copy: storing the planes pre-aligned (+ junk row)
-    would make this zero-copy thanks to input_output_aliases — partly
-    realized in PR 8 by the degenerate (Tc == 1) `core.layout.BlockedLayout`:
-    its stored tiles reshape to a plane already aligned in lanes and
-    8-multiple rows (`BlockedLayout.flat_view`; the engine remaps the
-    row-index stream via `BlockedLayout.pad_row_index`), leaving only this
-    wrapper's >=1 junk-row tail as a per-call pad.
+    skipped by the kernel whatever they hold); counts/p_i (W,); zj/pj
+    (W, C) per-entry operands. Planes are lane-padded to a multiple of 128
+    — the one remaining per-call copy, a no-op when C already is one (the
+    TPU-degenerate `core.layout.BlockedLayout` view); the per-entry operands
+    are padded to a multiple of 8 entries.
     """
     backend = backend or default_backend()
     HR, C = zij.shape
     W = rows.shape[0]
-    HRp = _round_up(HR + 1, 8)       # always >= 1 junk row for padding
+    Wp = _round_up(W, 8)
     Cp = _round_up(C, bcpnn_update.DEFAULT_BLOCK_L)
     interp = backend == "pallas_interpret"
-    rows_eff = jnp.where(jnp.arange(W) < jnp.asarray(nv, jnp.int32),
-                         jnp.clip(rows, 0, HRp - 1), HRp - 1)
     out = bcpnn_update.worklist_update_kernel_call(
-        _pad2(zij, HRp, Cp), _pad2(eij, HRp, Cp), _pad2(pij, HRp, Cp),
-        _pad2(wij, HRp, Cp), _pad2(tij, HRp, Cp, fill=0),
-        rows_eff, nv, now, counts,
-        _pad2(zj, W, Cp), p_i, _pad2(pj, W, Cp),
+        _pad2(zij, HR, Cp), _pad2(eij, HR, Cp), _pad2(pij, HR, Cp),
+        _pad2(wij, HR, Cp), _pad2(tij, HR, Cp),
+        _pad1(jnp.clip(rows, 0, HR - 1), Wp), nv, now, _pad1(counts, Wp),
+        _pad2(zj, Wp, Cp), _pad1(p_i, Wp), _pad2(pj, Wp, Cp),
         k=coeffs, eps=eps, interpret=interp)
-    return tuple(o[:HR, :C] for o in out)
+    return tuple(o[:, :C] for o in out)
+
+
+def _ivec_view(v, hrq: int):
+    """(HR,) i-vector as the (HRq, 128) lane-row view the row megakernel
+    patches in place (zero-padded to HRq * 128 cells)."""
+    return _pad1(v, hrq * 128).reshape(hrq, 128)
 
 
 def fused_row_update(zij, eij, pij, wij, tij, zi, ei, pi, ti, rows, now,
@@ -135,28 +131,29 @@ def fused_row_update(zij, eij, pij, wij, tij, zi, ei, pi, ti, rows, now,
     the H*R sentinel on padding/duplicate slots (no compaction: the grid is
     W steps either way, and slot order is what makes the weight-row output
     land h-major for free). counts/p_i/zi_new/ei_new/pi_new (W,);
-    zj/pj (W, C) per-entry operands. Sentinel entries are rerouted onto the
-    junk row region (>= H*R) added by the alignment padding, so a padding
-    grid step can never clobber a touched row.
+    zj/pj (W, C) per-entry operands. Sentinel slots touch no plane and
+    emit a zero weight row.
     Returns ((zij', eij', pij', wij', tij'), (zi', ei', pi', ti'), w_rows).
     """
     backend = backend or default_backend()
     HR, C = zij.shape
     W = rows.shape[0]
-    HRp = _round_up(HR + 1, 8)       # always >= 1 junk row for padding
+    Wp = _round_up(W, 8)
     Cp = _round_up(C, bcpnn_update.DEFAULT_BLOCK_L)
+    HRq = -(-HR // 128)
     interp = backend == "pallas_interpret"
-    rows_eff = jnp.where(rows < HR, jnp.clip(rows, 0, HRp - 1), HRp - 1)
-    iv2 = lambda v, fill=0: _pad1(v, HRp, fill).reshape(HRp, 1)
+    rows_eff = jnp.where((rows >= 0) & (rows < HR), rows, HR)
     out = bcpnn_update.fused_row_update_kernel_call(
-        _pad2(zij, HRp, Cp), _pad2(eij, HRp, Cp), _pad2(pij, HRp, Cp),
-        _pad2(wij, HRp, Cp), _pad2(tij, HRp, Cp, fill=0),
-        iv2(zi), iv2(ei), iv2(pi), iv2(ti),
-        rows_eff, now, counts, _pad2(zj, W, Cp), p_i, _pad2(pj, W, Cp),
-        zi_new, ei_new, pi_new, k=coeffs, eps=eps, hr=HR, interpret=interp)
-    flats = tuple(o[:HR, :C] for o in out[:5])
-    ivecs = tuple(o.reshape(HRp)[:HR] for o in out[5:9])
-    return flats, ivecs, out[9][:, :C]
+        _pad2(zij, HR, Cp), _pad2(eij, HR, Cp), _pad2(pij, HR, Cp),
+        _pad2(wij, HR, Cp), _pad2(tij, HR, Cp),
+        *(_ivec_view(v, HRq) for v in (zi, ei, pi, ti)),
+        _pad1(rows_eff, Wp, fill=HR), now, _pad1(counts, Wp),
+        _pad2(zj, Wp, Cp), _pad1(p_i, Wp), _pad2(pj, Wp, Cp),
+        _pad1(zi_new, Wp), _pad1(ei_new, Wp), _pad1(pi_new, Wp),
+        k=coeffs, eps=eps, hr=HR, interpret=interp)
+    flats = tuple(o[:, :C] for o in out[:5])
+    ivecs = tuple(o.reshape(-1)[:HR] for o in out[5:9])
+    return flats, ivecs, out[9][:W, :C]
 
 
 def fused_col_update(zij, eij, pij, wij, tij, h_idx, j_idx, now, zi_t, p_i,
@@ -207,7 +204,7 @@ def fused_col_update(zij, eij, pij, wij, tij, h_idx, j_idx, now, zi_t, p_i,
         _pad2(wij, HRp, Cp), _pad2(tij, HRp, Cp, fill=0),
         row_base, row_step, j_eff // L, j_eff % L, now,
         _pad2(zi_t.T, rows, L), _pad2(p_i.T, rows, L),
-        pj_sc.reshape(K, 1), k=coeffs, eps=eps, r=rows, bs=bs,
+        pj_sc, k=coeffs, eps=eps, r=rows, bs=bs,
         interpret=interp)
     return tuple(o[:HR, :C] for o in out)
 

@@ -7,17 +7,10 @@ xla_force_host_platform_device_count dance.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across versions: 0.4.x lacks the axis_types kwarg."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes, devices=devices)
     return jax.make_mesh(shape, axes, devices=devices,
                          axis_types=(AxisType.Auto,) * len(axes))
 

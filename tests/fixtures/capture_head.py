@@ -2,23 +2,27 @@
 
 Run from the repo root:
 
-    PYTHONPATH=src:tests python tests/fixtures/capture_head.py
+    JAX_PLATFORMS=cpu PYTHONPATH=src:tests python tests/fixtures/capture_head.py
 
-The engine-refactor bitwise-identity tests (tests/test_engine_fixtures.py)
-compare the live runtime against these files, so the fixtures pin the
-trajectory of the runtime AT THE COMMIT THEY WERE CAPTURED FROM. Regenerate
-them ONLY when a PR intentionally changes trajectories (and say so in the PR):
-the whole point of the TickEngine refactor contract is that trajectories do
-NOT change.
+The bitwise-identity tests (tests/test_engine_fixtures.py, and the legacy
+checkpoint test in tests/test_engine.py) compare the live runtime against
+these files, so the fixtures pin the trajectory of the runtime on the JAX
+build they were captured with: JAX 0.9.0, XLA:CPU. Regenerate them when a PR
+intentionally changes trajectories, or when the installed JAX changes (and
+say so in the PR). Bitwise identity holds within one JAX build and one
+backend only: JAX 0.5 changed the default PRNG stream
+(`jax_threefry_partitionable`), and XLA:CPU's rounding of the trace math
+moves by 1 ulp between releases. After regenerating, confirm that the modes
+that share parameters still agree with each other bitwise (lazy vs
+worklist, dense vs worklist, local vs sharded) and that the eager fixture
+fires the same spikes as the lazy ones.
 
 Fixtures store, per mode: the staged external input, the connectivity arrays,
 the fired history, and every NetworkState leaf (ij-planes reshaped to the
 canonical flat (H*R, C) layout so comparisons are layout-independent).
-
-Note: trajectories are bitwise-reproducible on a given machine/jax build;
-libm/codegen differences across machines can drift transcendentals by 1 ulp.
-If test_engine_fixtures fails on a *fresh* machine with tiny max-ulp diffs,
-regenerate the fixtures there and diff against git to confirm magnitude.
+`legacy_ckpt_ext.npz` stores the input of the legacy-checkpoint test and the
+continuation of the old-layout checkpoint `legacy_ckpt` (which itself is
+never rewritten: it is what the migration reads).
 """
 from __future__ import annotations
 
@@ -37,6 +41,7 @@ sys.path.insert(0, SRC)
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro.checkpoint import restore_network  # noqa: E402
 from repro.core import (init_network, make_connectivity, network_run,  # noqa: E402
                         run)
 from repro.core.params import BCPNNParams, test_scale  # noqa: E402
@@ -141,7 +146,26 @@ SHARDED_SCRIPT = textwrap.dedent("""
 """)
 
 
+def capture_legacy_continuation():
+    """Continue the old-layout checkpoint (t=10) to the end of its input and
+    store the fired history and final state next to that input."""
+    path = HERE / "legacy_ckpt_ext.npz"
+    d = dict(np.load(path))
+    p = test_scale(n_hcu=2, rows=32, cols=16)
+    key = jax.random.PRNGKey(0)
+    conn = make_connectivity(p, jax.random.fold_in(key, 1))
+    state = restore_network(str(HERE / "legacy_ckpt"), 10,
+                            init_network(p, key))
+    state, fired = network_run(state, conn, jnp.asarray(d["ext"][10:]), p)
+    keep = {k: d[k] for k in ("ext", "fired_prefix")}
+    np.savez_compressed(path, **keep, fired_cont=np.asarray(fired),
+                        **state_arrays(state, p))
+    print(f"captured legacy continuation: "
+          f"{int((np.asarray(fired) >= 0).sum())} spikes, t={int(state.t)}")
+
+
 def main():
+    capture_legacy_continuation()
     capture_local("lazy_dense", LAZY_P, worklist=False, seed=11, n_ticks=40,
                   lam=3.0, chunk=13)
     capture_local("lazy_worklist", LAZY_P, worklist=True, seed=11, n_ticks=40,

@@ -1,17 +1,17 @@
-"""TickEngine refactor contract: trajectories are bitwise-identical to the
-pre-refactor runtime.
+"""Trajectory contract: the runtime reproduces pinned trajectories bit for
+bit.
 
-tests/fixtures/head_*.npz hold trajectories captured from the runtime BEFORE
-the engine/flat-layout refactor (see tests/fixtures/capture_head.py): staged
-input, connectivity, fired history, and every NetworkState leaf (ij planes
-stored in the canonical flat (H*R, C) layout, which is a pure reshape of the
-old batched layout). The live runtime must reproduce them bit for bit in
-every mode — lazy / eager / merged, dense and worklist backends, scan and
+tests/fixtures/head_*.npz hold trajectories captured on JAX 0.9.0, XLA:CPU
+(see tests/fixtures/capture_head.py): staged input, connectivity, fired
+history, and every NetworkState leaf (ij planes stored in the canonical flat
+(H*R, C) layout). The live runtime must reproduce them bit for bit in every
+mode — lazy / eager / merged, dense and worklist backends, scan and
 host-loop drivers, local and sharded.
 
-If one of these fails after an INTENTIONAL trajectory change, regenerate the
-fixtures (and say so in the PR). On a fresh machine, 1-ulp libm/codegen
-drift is conceivable — see capture_head.py's note.
+Bitwise identity holds within one JAX build and one backend. If one of these
+fails after an INTENTIONAL trajectory change, or after the installed JAX
+changed, regenerate the fixtures (and say so in the PR) — capture_head.py
+says how, and what to check before committing them.
 """
 import os
 import pathlib
@@ -116,8 +116,8 @@ def test_trajectory_layout_invariant(name, tile):
 
 
 def test_sharded_trajectory_matches_pre_refactor():
-    """Both sharded backends vs the pre-refactor sharded runtime (subprocess:
-    device count must be set before jax initializes)."""
+    """Both sharded backends vs the sharded fixtures captured on JAX 0.9.0
+    (subprocess: device count must be set before jax initializes)."""
     script = textwrap.dedent("""
         import os, sys
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"

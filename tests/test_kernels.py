@@ -373,3 +373,42 @@ def test_fused_megakernel_sentinel_slots_are_noops():
     assert np.all(np.asarray(w_out)[[0, 2, 3, 4, 6, 7]] == 0.0), \
         "sentinel slots must emit zero weight rows"
     np.testing.assert_allclose(np.asarray(w_out), w_exp, rtol=3e-6, atol=3e-6)
+
+
+def test_worklist_kernels_same_tile_rows_not_adjacent():
+    """Two worklist rows of one (8, 128) tile that are NOT adjacent in entry
+    order — rows 3 and 5 with row 17 between them, plus rows 5 and 6 split
+    by sentinel slots — must both land, in both row kernels, and the fused
+    kernel's i-vector lane group that rows 3..6 share must keep every
+    write. A kernel that staged whole tiles through the block pipeline
+    would compute the later entry from, or write back, a copy of the tile
+    taken before the earlier entry's write. The kernels instead move each
+    row through VMEM with synchronous DMAs on the aliased buffers, so the
+    chip runs the sequence of reads and writes that this test checks."""
+    rng = np.random.default_rng(7)
+    HR, C, W = 32, 100, 8
+    a = _worklist_args(rng, HR, C, W, (3, 17, 5, 6), 4)
+    out = ops.worklist_row_update(**a, coeffs=K, eps=EPS,
+                                  backend="pallas_interpret")
+    exp = _worklist_expected(a, HR, C, 4)
+    for o, ex, name in zip(out, exp, "zepwt"):
+        np.testing.assert_allclose(np.asarray(o), ex, rtol=3e-6, atol=3e-6,
+                                   err_msg=f"worklist plane {name}")
+    for r in (3, 5, 6):
+        assert np.all(np.asarray(out[4])[r] == a["now"]), f"row {r} stamped"
+
+    a = _fused_args(rng, HR, C, W, ())
+    # slot order: tile 0 (rows 3, 5, 6) revisited around tile 2 and
+    # sentinel slots; rows 3..6 also share one i-vector lane group
+    a["rows"] = jnp.asarray([3, HR, 17, 5, HR, 6, HR, HR], jnp.int32)
+    flats, ivecs, w_out = ops.fused_row_update(
+        **a, coeffs=K, eps=EPS, backend="pallas_interpret")
+    exp, iv_exp, w_exp = _fused_expected(a, HR, C, W)
+    for o, ex, name in zip(flats, exp, "zepwt"):
+        np.testing.assert_allclose(np.asarray(o), ex, rtol=3e-6, atol=3e-6,
+                                   err_msg=f"fused plane {name}")
+    for o, ex, name in zip(ivecs, iv_exp, ("zi", "ei", "pi", "ti")):
+        np.testing.assert_array_equal(np.asarray(o), ex,
+                                      err_msg=f"i-vector {name}")
+    np.testing.assert_allclose(np.asarray(w_out), w_exp, rtol=3e-6,
+                               atol=3e-6)

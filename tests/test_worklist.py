@@ -70,6 +70,31 @@ def _run_both(p, ext, merged=False, chunk=16, key_seed=0, fused=None,
     return sa, fa, sb, fb
 
 
+# Two DIFFERENT Pallas programs run in interpret mode (a worklist kernel vs
+# the dense row kernel) are each compiled by XLA:CPU on their own, and it may
+# round the same cell math 1 ulp apart (docs/NUMERICS.md). Such pairs are
+# held to this many ulp on float planes; fired histories, integer planes and
+# queues stay exact.
+INTERPRET_MAXULP = 2
+
+
+def _assert_within_ulp(sa, fa, sb, fb, maxulp=INTERPRET_MAXULP):
+    np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
+    for name in sa.hcus._fields:
+        a, b = np.asarray(getattr(sa.hcus, name)), \
+            np.asarray(getattr(sb.hcus, name))
+        if a.dtype.kind == "f":
+            np.testing.assert_array_max_ulp(a, b, maxulp=maxulp)
+        else:
+            np.testing.assert_array_equal(a, b, err_msg=f"plane {name}")
+    np.testing.assert_array_equal(np.asarray(sa.delay_rows),
+                                  np.asarray(sb.delay_rows))
+    np.testing.assert_array_equal(np.asarray(sa.delay_count),
+                                  np.asarray(sb.delay_count))
+    assert int(sa.drops_in) == int(sb.drops_in)
+    assert int(sa.drops_fire) == int(sb.drops_fire)
+
+
 def _assert_bitwise(sa, fa, sb, fb, merged=False):
     np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
     for name in sa.hcus._fields:
@@ -262,8 +287,9 @@ def test_pallas_interpret_blocked_layout_matches_flat(xr):
 
 def test_pallas_interpret_worklist_matches_vmap_path():
     """The non-fused scalar-prefetch Pallas worklist kernel (interpret mode)
-    must reproduce the vmapped pallas-interpret path exactly: both run the
-    same kernel cell math, so even the weight planes match bitwise."""
+    must reproduce the vmapped pallas-interpret path: the same fired
+    history, and planes within INTERPRET_MAXULP (the two sides are
+    different kernels, compiled separately)."""
     ext = _ext_tensor(LAZY_P, seed=3, n_ticks=12, lam=3.0)
     key = jax.random.PRNGKey(0)
     conn = make_connectivity(LAZY_P, jax.random.fold_in(key, 1))
@@ -272,7 +298,8 @@ def test_pallas_interpret_worklist_matches_vmap_path():
     sb, fb = network_run(init_network(LAZY_P, key), conn, ext, LAZY_P,
                          chunk=12, worklist=True, fused=False,
                          backend="pallas_interpret")
-    _assert_bitwise(sa, fa, sb, fb)
+    assert (np.asarray(fa) >= 0).sum() > 0
+    _assert_within_ulp(sa, fa, sb, fb)
 
 
 @pytest.mark.parametrize("fused_cols", [False, True])
