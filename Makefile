@@ -1,7 +1,7 @@
 # Tier-1 verification (mirrors .github/workflows/ci.yml)
 PY ?= python
 
-.PHONY: verify test bench bench-json profile resilience weak-scaling \
+.PHONY: verify test bench bench-json resilience weak-scaling \
 	check-pycache ci-local
 
 verify: test bench
@@ -18,13 +18,6 @@ bench:
 bench-json:
 	PYTHONPATH=src $(PY) -m benchmarks.run --fast --json --legacy-cpu
 	PYTHONPATH=src $(PY) -m benchmarks.render_bench_table
-
-# tick-loop numbers (default + rodent16 + human_col) plus the per-phase
-# scan-context ablation (queue / row / WTA / column, measured as deltas on
-# the scan path itself) written to BENCH_phase_breakdown.json — read
-# docs/BENCHMARKING.md before trusting the isolated numbers also printed
-profile: bench-json
-	PYTHONPATH=src $(PY) -m benchmarks.profile_phases --legacy-cpu
 
 # fault-injection suite (incl. the multi-device elastic smoke — forced
 # host-platform device count, subprocess-isolated) + resilience telemetry
@@ -62,9 +55,7 @@ check-pycache:
 # the COMMITTED JSON (taken from HEAD, not the working tree, so repeated
 # runs cannot compound a slow drift past the gate; note the fresh
 # measurement is left in BENCH_tick_loop.json afterwards, same as `make
-# bench-json`) -> per-phase ablation artifact + the human_col column-phase
-# gate (the phase the PR 8 column-blocked layout targets) -> the Fig 10
-# layout benchmark (BENCH_layout.json: paper DRAM model + tile models +
+# bench-json`) -> the Fig 10 layout benchmark (BENCH_layout.json: paper DRAM model + tile models +
 # measured CPU flat/blocked A/B) + its layout-model gate -> the serving
 # benchmark (BENCH_serving.json: continuous-batching recall QPS at
 # rodent16) + its QPS-at-SLO gate -> the weak-scaling sweep
@@ -75,17 +66,12 @@ ci-local: check-pycache
 	git diff --exit-code README.md
 	$(MAKE) test bench
 	git show HEAD:BENCH_tick_loop.json > /tmp/BENCH_committed.json
-	git show HEAD:BENCH_phase_breakdown.json > /tmp/BENCH_phase_committed.json
 	git show HEAD:BENCH_serving.json > /tmp/BENCH_serving_committed.json
 	git show HEAD:BENCH_layout.json > /tmp/BENCH_layout_committed.json
 	git show HEAD:BENCH_weak_scaling.json > /tmp/BENCH_weak_committed.json
 	PYTHONPATH=src $(PY) -m benchmarks.run --fast --json --legacy-cpu
 	PYTHONPATH=src $(PY) -m benchmarks.check_regression \
 		--committed /tmp/BENCH_committed.json
-	PYTHONPATH=src $(PY) -m benchmarks.profile_phases --legacy-cpu
-	PYTHONPATH=src $(PY) -m benchmarks.check_regression \
-		--committed /tmp/BENCH_committed.json \
-		--phase-committed /tmp/BENCH_phase_committed.json
 	PYTHONPATH=src $(PY) -m benchmarks.fig10_rowmerge --legacy-cpu
 	PYTHONPATH=src $(PY) -m benchmarks.check_regression \
 		--layout-committed /tmp/BENCH_layout_committed.json

@@ -2,8 +2,6 @@
 
   PYTHONPATH=src python -m benchmarks.check_regression \
       [--committed /tmp/BENCH_committed.json --fresh BENCH_tick_loop.json] \
-      [--phase-committed /tmp/BENCH_phase_committed.json \
-       --phase-fresh BENCH_phase_breakdown.json] \
       [--serving-committed /tmp/BENCH_serving_committed.json \
        --serving-fresh BENCH_serving.json] \
       [--weak-scaling-committed /tmp/BENCH_weak_committed.json \
@@ -11,18 +9,13 @@
       [--layout-committed /tmp/BENCH_layout_committed.json \
        --layout-fresh BENCH_layout.json]
 
-Five gates, all optional and all with the same headroom philosophy —
+Four gates, all optional and all with the same headroom philosophy —
 headroom absorbs CI-runner noise while still catching the step-function
 regressions that matter (a lost in-place alias or an accidental full-plane
 copy is 2x+, never 1.1x):
 
   * tick loop — any gated size's `scan_us_per_tick` in BENCH_tick_loop.json
     vs the committed baseline (1.25x headroom);
-  * column phase (optional, when --phase-committed is given) — the
-    human_col `column_update` scan-context ablation delta in
-    BENCH_phase_breakdown.json. This is the phase the PR 8 column-blocked
-    layout targets, gated so a later change can't silently hand the
-    Row-Merge win back (docs/BENCHMARKING.md);
   * serving throughput (optional, when --serving-committed is given) — the
     rodent16 `qps_at_slo` in BENCH_serving.json. This gate is INVERTED
     (higher is better): it fails when the fresh throughput drops below
@@ -55,8 +48,6 @@ import sys
 
 GATED_SIZES = ("default", "rodent16", "human_col")
 METRIC = "scan_us_per_tick"
-# (size, ablated phase) pairs gated when a phase baseline is supplied
-GATED_PHASES = (("human_col", "column_update"),)
 HEADROOM = 1.25
 SERVING_METRIC = "qps_at_slo"
 SERVING_HEADROOM = 2.0
@@ -71,11 +62,6 @@ def main() -> None:
                          "enables the tick-loop gate")
     ap.add_argument("--fresh", default="BENCH_tick_loop.json",
                     help="path to the freshly measured tick-loop JSON")
-    ap.add_argument("--phase-committed", default=None,
-                    help="committed (baseline) phase-breakdown JSON; "
-                         "enables the column-phase gate")
-    ap.add_argument("--phase-fresh", default="BENCH_phase_breakdown.json",
-                    help="freshly measured phase-breakdown JSON")
     ap.add_argument("--serving-committed", default=None,
                     help="committed (baseline) serving JSON; enables the "
                          "rodent16 QPS-at-SLO gate")
@@ -110,20 +96,6 @@ def main() -> None:
                 failures.append(f"{name}/{METRIC} {new:.1f} us exceeds "
                                 f"committed {old:.1f} us by "
                                 f">{args.headroom:.2f}x")
-
-    if args.phase_committed:
-        pc = json.load(open(args.phase_committed))
-        pf = json.load(open(args.phase_fresh))
-        for name, phase in GATED_PHASES:
-            old = pc[name]["scan_ablation_us"][phase]
-            new = pf[name]["scan_ablation_us"][phase]
-            print(f"{name}/ablation/{phase}: committed {old:.1f} us, fresh "
-                  f"{new:.1f} us ({new / old:.2f}x, "
-                  f"limit {args.headroom:.2f}x)")
-            if new > old * args.headroom:
-                failures.append(
-                    f"{name}/ablation/{phase} {new:.1f} us exceeds committed "
-                    f"{old:.1f} us by >{args.headroom:.2f}x")
 
     if args.serving_committed:
         sc = json.load(open(args.serving_committed))

@@ -20,9 +20,8 @@ artifact next to BENCH_tick_loop.json). Three sections:
     A/B of the worklist column phase at the human_col bench size
     (benchmarks/tick_loop.py), canonical flat vs the column-blocked CPU
     tile: the full production scan (`engine.tick` under `lax.scan`,
-    donated carry) minus the same scan with the column phase ablated, the
-    scan-context methodology of benchmarks/profile_phases.py. Estimator is
-    MIN over interleaved repeats (contention is additive noise; see
+    donated carry) minus the same scan with the column phase ablated.
+    Estimator is MIN over interleaved repeats (contention is additive noise; see
     tick_loop.py). Caveats: ablation deltas are O(phase) accurate, not
     exact — ablating the column phase perturbs downstream spike
     trajectories — and the flat/blocked deltas come from separately
@@ -55,8 +54,8 @@ def measure_column_ab(p, layouts, ticks=64, repeats=5):
 
     class _NoColumns(NamedTuple):
         """Worklist backend with the lazy column phase swapped for a no-op
-        (benchmark-only recomposition — tracks WorklistBackend.plane_update
-        the same way profile_phases.AblatedBackend does)."""
+        (benchmark-only recomposition — tracks
+        WorklistBackend.plane_update)."""
         base: object
 
         def carry_in(self, state, p):

@@ -22,8 +22,8 @@ Three sizes are measured (CPU `ref` backend):
     PR 4.
   * human_col_blocked — the same slab stored under the Row-Merge
     column-blocked plane layout (PR 8, `layout="blocked"`): the end-to-end
-    per-tick layout A/B. Not regression-gated; the targeted column-phase
-    gate runs on the BENCH_phase_breakdown.json ablation.
+    per-tick layout A/B. Not regression-gated; the layout-model gate holds
+    the BENCH_layout.json column A/B (benchmarks/fig10_rowmerge.py).
 
 All sizes are driven through the `Simulator` facade (scan runtime
 `sim.run` vs host loop `sim.run_host`).
@@ -33,8 +33,7 @@ The committed numbers are measured with `--legacy-cpu` (benchmarks.run's
 opt-in pin of `--xla_cpu_use_thunk_runtime=false`): the legacy XLA CPU
 runtime executes the identical HLO with ~3-4x lower per-op overhead, for
 the host loop and the scan runtime alike. docs/BENCHMARKING.md has the
-full workflow (regenerating the JSON, the CI regression gate, `make
-profile`).
+full workflow (regenerating the JSON, the CI regression gate).
 """
 from __future__ import annotations
 
@@ -61,8 +60,8 @@ HUMAN_COL = ("human_col", BCPNNParams(n_hcu=4, rows=HUMAN_CFG.rows,
 # the same slab under the PR 8 Row-Merge column-blocked plane layout
 # (layout="blocked", the CPU tile) — an end-to-end per-tick A/B against
 # human_col in the same committed JSON; NOT regression-gated (the flat
-# entries stay the gated baseline), the column-phase gate lives on the
-# BENCH_phase_breakdown.json ablation instead
+# entries stay the gated baseline), the column A/B is gated through
+# BENCH_layout.json instead
 HUMAN_COL_BLOCKED = ("human_col_blocked", HUMAN_COL[1], "blocked")
 
 N_SCAN = 128         # ticks per measured scan call (one compiled chunk)

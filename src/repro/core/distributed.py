@@ -327,8 +327,11 @@ def make_dist_run(mesh: Mesh, p: BCPNNParams, rc: RouteConfig,
         def body(s, e):
             return _local_tick(s, conn, e, p=p, rc=rc, axis=axes, be=be,
                                overlap=overlap)
-        state, fired = jax.lax.scan(body, be.carry_in(state, p), ext)
-        return be.carry_out(state, p), fired
+        with jax.named_scope("carry"):
+            carry = be.carry_in(state, p)
+        state, fired = jax.lax.scan(body, carry, ext)
+        with jax.named_scope("carry"):
+            return be.carry_out(state, p), fired
 
     fn = jax.shard_map(
         _local_run,

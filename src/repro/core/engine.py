@@ -215,6 +215,15 @@ def _wta(hcus: H.HCUState, w_rows, counts, t, keys, p: BCPNNParams):
     return hcus._replace(h=h_new), fired
 
 
+def _in_scope(name: str, fn):
+    """`fn`, traced under the named scope `name` wherever it is called (the
+    column pass is built in one phase and run later by `tick`)."""
+    def scoped(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+    return scoped
+
+
 def _col_worklist_prologue(hcus: H.HCUState, h_idx, j_idx, now,
                            p: BCPNNParams, n: int):
     """Shared fused/staged column prologue: per-entry presynaptic traces
@@ -336,10 +345,7 @@ def worklist_col_dispatch(kernel, fused_cols, h_idx, j_idx, t,
     resolved kernel backend: the in-place loops (`_column_worklist`,
     fused or staged) on "ref", the `ops.fused_col_update` megakernel or
     the batched-view kernel on the Pallas backends. Returns a
-    hcus -> hcus' closure. Exposed (not underscored) because
-    `benchmarks/profile_phases.py`'s ablation harness reuses it — the
-    published per-phase deltas must dispatch exactly what the engine
-    dispatches.
+    hcus -> hcus' closure.
 
     ``layout`` (a `layout.BlockedLayout` or None) selects the storage order
     the closures address. The Pallas megakernel only speaks the flat view,
@@ -387,8 +393,8 @@ def worklist_lazy_rows(hcus: H.HCUState, rows, t, p: BCPNNParams,
     remapped onto the padded row pitch, and fall back to a canonical-flat
     round-trip otherwise.
 
-    Exposed (not underscored) because `benchmarks/profile_phases.py` times it
-    as the row-update phase.
+    Exposed (not underscored) because `benchmarks/fig10_rowmerge.py`
+    rebuilds the worklist tick around it.
     """
     lay = L.as_blocked(layout)
     kb = kernel or ops.default_backend()
@@ -711,25 +717,34 @@ class DenseBackend(NamedTuple):
     def plane_update_split(self, state, rows, t, keys, p: BCPNNParams,
                            cap: int, cond_columns: bool):
         n = state.delay_rows.shape[0]
+        # each dense mode updates rows and runs the WTA in one vmapped call
+        # (eager and merged also their columns): it reads as the row phase
         if self.mode == "eager":
-            hcus, fired = jax.vmap(
-                lambda s, r, k: reference.eager_tick(s, r, t, k, p)
-            )(state.hcus, rows, keys)
-            h_idx, j_idx, n_drop = N.select_fired(fired, cap)
+            with jax.named_scope("rows"):
+                hcus, fired = jax.vmap(
+                    lambda s, r, k: reference.eager_tick(s, r, t, k, p)
+                )(state.hcus, rows, keys)
+            with jax.named_scope("wta"):
+                h_idx, j_idx, n_drop = N.select_fired(fired, cap)
             return (state._replace(hcus=hcus), fired, h_idx, j_idx, n_drop,
                     None)
         if self.mode == "merged":
             from repro.core import merged as M
-            hcus, jring, fired = jax.vmap(
-                lambda s, g, r, k: M.hcu_tick_merged(s, g, r, t, k, p)
-            )(state.hcus, state.jring, rows, keys)
-            h_idx, j_idx, n_drop = N.select_fired(fired, cap)
+            with jax.named_scope("rows"):
+                hcus, jring, fired = jax.vmap(
+                    lambda s, g, r, k: M.hcu_tick_merged(s, g, r, t, k, p)
+                )(state.hcus, state.jring, rows, keys)
+            with jax.named_scope("wta"):
+                h_idx, j_idx, n_drop = N.select_fired(fired, cap)
             return (state._replace(hcus=hcus, jring=jring), fired,
                     h_idx, j_idx, n_drop, None)
-        hcus, fired = jax.vmap(
-            lambda s, r, k: H.hcu_tick_pre(s, r, t, k, p, backend=self.kernel)
-        )(state.hcus, rows, keys)
-        h_idx, j_idx, n_drop = N.select_fired(fired, cap)
+        with jax.named_scope("rows"):
+            hcus, fired = jax.vmap(
+                lambda s, r, k: H.hcu_tick_pre(s, r, t, k, p,
+                                               backend=self.kernel)
+            )(state.hcus, rows, keys)
+        with jax.named_scope("wta"):
+            h_idx, j_idx, n_drop = N.select_fired(fired, cap)
         col = lambda hc: column_updates_batched(hc, h_idx, j_idx, t, p,
                                                 backend=self.kernel)
         if cond_columns:
@@ -739,7 +754,8 @@ class DenseBackend(NamedTuple):
                                             lambda hc_: hc_, hc)
         else:
             colfn = col
-        return state._replace(hcus=hcus), fired, h_idx, j_idx, n_drop, colfn
+        return (state._replace(hcus=hcus), fired, h_idx, j_idx, n_drop,
+                _in_scope("cols", colfn))
 
 
 class WorklistBackend(NamedTuple):
@@ -791,18 +807,24 @@ class WorklistBackend(NamedTuple):
                            cap: int, cond_columns: bool):
         n = state.delay_rows.shape[0]
         if self.mode == "merged":
-            hcus, jring, fired = _merged_worklist_update(
-                state.hcus, state.jring, rows, t, keys, p, fused=self.fused,
-                layout=self.layout)
-            h_idx, j_idx, n_drop = N.select_fired(fired, cap)
+            # rows, WTA and the merged column flush are one call: it reads
+            # as the row phase
+            with jax.named_scope("rows"):
+                hcus, jring, fired = _merged_worklist_update(
+                    state.hcus, state.jring, rows, t, keys, p,
+                    fused=self.fused, layout=self.layout)
+            with jax.named_scope("wta"):
+                h_idx, j_idx, n_drop = N.select_fired(fired, cap)
             return (state._replace(hcus=hcus, jring=jring), fired,
                     h_idx, j_idx, n_drop, None)
-        hcus, w_rows, c = worklist_lazy_rows(state.hcus, rows, t, p,
-                                             kernel=self.kernel,
-                                             fused=self.fused,
-                                             layout=self.layout)
-        hcus, fired = _wta(hcus, w_rows, c["counts"], t, keys, p)
-        h_idx, j_idx, n_drop = N.select_fired(fired, cap)
+        with jax.named_scope("rows"):
+            hcus, w_rows, c = worklist_lazy_rows(state.hcus, rows, t, p,
+                                                 kernel=self.kernel,
+                                                 fused=self.fused,
+                                                 layout=self.layout)
+        with jax.named_scope("wta"):
+            hcus, fired = _wta(hcus, w_rows, c["counts"], t, keys, p)
+            h_idx, j_idx, n_drop = N.select_fired(fired, cap)
         col = worklist_col_dispatch(self.kernel, self.fused_cols,
                                     h_idx, j_idx, t, p, n,
                                     layout=self.layout)
@@ -811,7 +833,8 @@ class WorklistBackend(NamedTuple):
                                             lambda hc_: hc_, hc)
         else:
             colfn = col
-        return state._replace(hcus=hcus), fired, h_idx, j_idx, n_drop, colfn
+        return (state._replace(hcus=hcus), fired, h_idx, j_idx, n_drop,
+                _in_scope("cols", colfn))
 
 
 def select_backend(p: BCPNNParams, *, eager: bool = False,
@@ -880,45 +903,59 @@ def tick(state, conn, ext_rows, p: BCPNNParams, be: "TickBackend",
     Returns (state', fired) with fired[h] = MCU index or -1.
     """
     n = state.delay_rows.shape[0]
-    t = state.t + 1
     cap = cap_fire or max(2, int(0.35 * n) + 1)
-
-    # 1. consume this tick's delay bucket and merge with external input
-    state, bucket = N.consume_bucket(state, t, p, n)
-    rows = jnp.concatenate([bucket, ext_rows], axis=1)
-
-    # 2. plane update (rows + WTA + columns), identical RNG in all drivers
-    k_t = jax.random.fold_in(state.base_key, t)
-    gids = gid_base + jnp.arange(n)
-    keys = jax.vmap(lambda g: jax.random.fold_in(k_t, g))(gids)
     split = route is not None and hasattr(route, "send")
-    if split:
-        # split-phase route: defer the column pass so the spike collective
-        # can be issued between WTA and columns (overlap window)
-        state, fired, h_idx, j_idx, n_drop, col = be.plane_update_split(
-            state, rows, t, keys, p, cap, cond_columns)
-    else:
-        state, fired, h_idx, j_idx, n_drop = be.plane_update(
-            state, rows, t, keys, p, cap, cond_columns)
-        col = None
-    state = state._replace(drops_fire=state.drops_fire + n_drop, t=t)
 
-    # 3. fan out spikes from the fired batch into delay queues
-    safe_h = jnp.minimum(h_idx, n - 1)
-    dest_h = conn.dest_hcu[safe_h, j_idx].reshape(-1)          # (K*F,)
-    dest_r = conn.dest_row[safe_h, j_idx].reshape(-1)
-    dly = conn.delay[safe_h, j_idx].reshape(-1)
-    valid = jnp.repeat(h_idx < n, p.fanout)
-    if split:
-        # 3a. compact + issue the all_to_all; 2b. columns run while the
-        # exchange is in flight; 3b. enqueue the delivered spikes
-        state, inflight = route.send(state, dest_h, dest_r, dly, valid, p, n)
-        if col is not None:
-            state = state._replace(hcus=col(state.hcus))
-        state = route.recv(state, inflight, p, n)
-    else:
-        state = (route or N.enqueue_spikes)(state, dest_h, dest_r, dly,
-                                            valid, p, n)
+    # named scopes only label the HLO ops (metadata op_name) for the device
+    # trace: queue / rows / wta / cols partition the tick, and the backends
+    # open rows, wta and cols where they make those calls
+    with jax.named_scope("tick"):
+        # 1. consume this tick's delay bucket and merge with external input
+        with jax.named_scope("queue"):
+            t = state.t + 1
+            state, bucket = N.consume_bucket(state, t, p, n)
+            rows = jnp.concatenate([bucket, ext_rows], axis=1)
+
+        # 2. plane update (rows + WTA + columns), identical RNG in all
+        # drivers
+        with jax.named_scope("wta"):
+            k_t = jax.random.fold_in(state.base_key, t)
+            gids = gid_base + jnp.arange(n)
+            keys = jax.vmap(lambda g: jax.random.fold_in(k_t, g))(gids)
+        if split:
+            # split-phase route: defer the column pass so the spike
+            # collective can be issued between WTA and columns (overlap
+            # window)
+            state, fired, h_idx, j_idx, n_drop, col = be.plane_update_split(
+                state, rows, t, keys, p, cap, cond_columns)
+        else:
+            state, fired, h_idx, j_idx, n_drop = be.plane_update(
+                state, rows, t, keys, p, cap, cond_columns)
+            col = None
+        with jax.named_scope("wta"):
+            state = state._replace(drops_fire=state.drops_fire + n_drop, t=t)
+
+        # 3. fan out spikes from the fired batch into delay queues
+        with jax.named_scope("queue"):
+            safe_h = jnp.minimum(h_idx, n - 1)
+            dest_h = conn.dest_hcu[safe_h, j_idx].reshape(-1)      # (K*F,)
+            dest_r = conn.dest_row[safe_h, j_idx].reshape(-1)
+            dly = conn.delay[safe_h, j_idx].reshape(-1)
+            valid = jnp.repeat(h_idx < n, p.fanout)
+            if split:
+                # 3a. compact + issue the all_to_all
+                state, inflight = route.send(state, dest_h, dest_r, dly,
+                                             valid, p, n)
+            else:
+                state = (route or N.enqueue_spikes)(state, dest_h, dest_r,
+                                                    dly, valid, p, n)
+        if split:
+            # 2b. columns run while the exchange is in flight; 3b. enqueue
+            # the delivered spikes
+            if col is not None:
+                state = state._replace(hcus=col(state.hcus))
+            with jax.named_scope("queue"):
+                state = route.recv(state, inflight, p, n)
     return state, fired
 
 

@@ -364,8 +364,11 @@ def _run_chunk(state: NetworkState, conn: Connectivity, ext: jnp.ndarray,
     def body(s, e):
         return E.tick(s, conn, e, p, be, cap_fire)
 
-    state, fired = jax.lax.scan(body, be.carry_in(state, p), ext)
-    return be.carry_out(state, p), fired
+    with jax.named_scope("carry"):
+        carry = be.carry_in(state, p)
+    state, fired = jax.lax.scan(body, carry, ext)
+    with jax.named_scope("carry"):
+        return be.carry_out(state, p), fired
 
 
 def network_run(state: NetworkState, conn: Connectivity, ext: jnp.ndarray,

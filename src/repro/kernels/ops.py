@@ -8,7 +8,10 @@ Backends:
 
 Selected via REPRO_KERNEL_BACKEND or the explicit ``backend=`` argument.
 The wrappers own all shape plumbing (padding to (8,128) tiles, column
-reshape), so callers deal only in logical (S, C) / (R,) shapes.
+reshape), so callers deal only in logical (S, C) / (R,) shapes. That
+padding, and the slice back to the logical shape, is traced under the
+named scope `lane_pad` (`LANE_PAD`), so a device trace can tell its copies
+from the kernels' own time.
 """
 from __future__ import annotations
 
@@ -19,6 +22,8 @@ import jax.numpy as jnp
 
 from repro.core.traces import DecayCoeffs
 from repro.kernels import bcpnn_ref, bcpnn_update
+
+LANE_PAD = "lane_pad"
 
 
 def default_backend() -> str:
@@ -66,12 +71,15 @@ def row_update(zij, eij, pij, tij, now, counts, zj, p_i, p_j,
     bs = min(bcpnn_update.DEFAULT_BLOCK_S, _round_up(S, 8))
     Sp, Cp = _round_up(S, bs), _round_up(C, bcpnn_update.DEFAULT_BLOCK_L)
     interp = backend == "pallas_interpret"
+    with jax.named_scope(LANE_PAD):
+        planes = (_pad2(zij, Sp, Cp), _pad2(eij, Sp, Cp), _pad2(pij, Sp, Cp),
+                  _pad2(wij, Sp, Cp), _pad2(tij, Sp, Cp, fill=0))
+        vecs = (_pad1(counts, Sp), _pad1(zj, Cp), _pad1(p_i, Sp),
+                _pad1(p_j, Cp))
     out = bcpnn_update.row_update_kernel_call(
-        _pad2(zij, Sp, Cp), _pad2(eij, Sp, Cp), _pad2(pij, Sp, Cp),
-        _pad2(wij, Sp, Cp), _pad2(tij, Sp, Cp, fill=0), now,
-        _pad1(counts, Sp), _pad1(zj, Cp), _pad1(p_i, Sp), _pad1(p_j, Cp),
-        k=coeffs, eps=eps, bs=bs, interpret=interp)
-    return tuple(o[:S, :C] for o in out)
+        *planes, now, *vecs, k=coeffs, eps=eps, bs=bs, interpret=interp)
+    with jax.named_scope(LANE_PAD):
+        return tuple(o[:S, :C] for o in out)
 
 
 def worklist_row_update(zij, eij, pij, wij, tij, rows, nv, now, counts, zj,
@@ -96,13 +104,17 @@ def worklist_row_update(zij, eij, pij, wij, tij, rows, nv, now, counts, zj,
     Wp = _round_up(W, 8)
     Cp = _round_up(C, bcpnn_update.DEFAULT_BLOCK_L)
     interp = backend == "pallas_interpret"
+    with jax.named_scope(LANE_PAD):
+        planes = (_pad2(zij, HR, Cp), _pad2(eij, HR, Cp), _pad2(pij, HR, Cp),
+                  _pad2(wij, HR, Cp), _pad2(tij, HR, Cp))
+        rows_p = _pad1(jnp.clip(rows, 0, HR - 1), Wp)
+        counts_p = _pad1(counts, Wp)
+        entries = (_pad2(zj, Wp, Cp), _pad1(p_i, Wp), _pad2(pj, Wp, Cp))
     out = bcpnn_update.worklist_update_kernel_call(
-        _pad2(zij, HR, Cp), _pad2(eij, HR, Cp), _pad2(pij, HR, Cp),
-        _pad2(wij, HR, Cp), _pad2(tij, HR, Cp),
-        _pad1(jnp.clip(rows, 0, HR - 1), Wp), nv, now, _pad1(counts, Wp),
-        _pad2(zj, Wp, Cp), _pad1(p_i, Wp), _pad2(pj, Wp, Cp),
+        *planes, rows_p, nv, now, counts_p, *entries,
         k=coeffs, eps=eps, interpret=interp)
-    return tuple(o[:, :C] for o in out)
+    with jax.named_scope(LANE_PAD):
+        return tuple(o[:, :C] for o in out)
 
 
 def _ivec_view(v, hrq: int):
@@ -143,17 +155,21 @@ def fused_row_update(zij, eij, pij, wij, tij, zi, ei, pi, ti, rows, now,
     HRq = -(-HR // 128)
     interp = backend == "pallas_interpret"
     rows_eff = jnp.where((rows >= 0) & (rows < HR), rows, HR)
+    with jax.named_scope(LANE_PAD):
+        planes = (_pad2(zij, HR, Cp), _pad2(eij, HR, Cp), _pad2(pij, HR, Cp),
+                  _pad2(wij, HR, Cp), _pad2(tij, HR, Cp),
+                  *(_ivec_view(v, HRq) for v in (zi, ei, pi, ti)))
+        rows_p = _pad1(rows_eff, Wp, fill=HR)
+        entries = (_pad1(counts, Wp), _pad2(zj, Wp, Cp), _pad1(p_i, Wp),
+                   _pad2(pj, Wp, Cp), _pad1(zi_new, Wp), _pad1(ei_new, Wp),
+                   _pad1(pi_new, Wp))
     out = bcpnn_update.fused_row_update_kernel_call(
-        _pad2(zij, HR, Cp), _pad2(eij, HR, Cp), _pad2(pij, HR, Cp),
-        _pad2(wij, HR, Cp), _pad2(tij, HR, Cp),
-        *(_ivec_view(v, HRq) for v in (zi, ei, pi, ti)),
-        _pad1(rows_eff, Wp, fill=HR), now, _pad1(counts, Wp),
-        _pad2(zj, Wp, Cp), _pad1(p_i, Wp), _pad2(pj, Wp, Cp),
-        _pad1(zi_new, Wp), _pad1(ei_new, Wp), _pad1(pi_new, Wp),
-        k=coeffs, eps=eps, hr=HR, interpret=interp)
-    flats = tuple(o[:, :C] for o in out[:5])
-    ivecs = tuple(o.reshape(-1)[:HR] for o in out[5:9])
-    return flats, ivecs, out[9][:W, :C]
+        *planes, rows_p, now, *entries, k=coeffs, eps=eps, hr=HR,
+        interpret=interp)
+    with jax.named_scope(LANE_PAD):
+        flats = tuple(o[:, :C] for o in out[:5])
+        ivecs = tuple(o.reshape(-1)[:HR] for o in out[5:9])
+        return flats, ivecs, out[9][:W, :C]
 
 
 def fused_col_update(zij, eij, pij, wij, tij, h_idx, j_idx, now, zi_t, p_i,
@@ -199,14 +215,18 @@ def fused_col_update(zij, eij, pij, wij, tij, h_idx, j_idx, now, zi_t, p_i,
                          HR // bs)
     row_step = valid.astype(jnp.int32)
     j_eff = jnp.where(valid, jnp.clip(j_idx, 0, C - 1), 0)
+    with jax.named_scope(LANE_PAD):
+        planes = (_pad2(zij, HRp, Cp), _pad2(eij, HRp, Cp),
+                  _pad2(pij, HRp, Cp), _pad2(wij, HRp, Cp),
+                  _pad2(tij, HRp, Cp, fill=0))
+    lane_tile, lane = j_eff // L, j_eff % L
+    with jax.named_scope(LANE_PAD):
+        presyn = (_pad2(zi_t.T, rows, L), _pad2(p_i.T, rows, L))
     out = bcpnn_update.fused_col_update_kernel_call(
-        _pad2(zij, HRp, Cp), _pad2(eij, HRp, Cp), _pad2(pij, HRp, Cp),
-        _pad2(wij, HRp, Cp), _pad2(tij, HRp, Cp, fill=0),
-        row_base, row_step, j_eff // L, j_eff % L, now,
-        _pad2(zi_t.T, rows, L), _pad2(p_i.T, rows, L),
-        pj_sc, k=coeffs, eps=eps, r=rows, bs=bs,
-        interpret=interp)
-    return tuple(o[:HR, :C] for o in out)
+        *planes, row_base, row_step, lane_tile, lane, now, *presyn,
+        pj_sc, k=coeffs, eps=eps, r=rows, bs=bs, interpret=interp)
+    with jax.named_scope(LANE_PAD):
+        return tuple(o[:HR, :C] for o in out)
 
 
 def col_update(z_col, e_col, p_col, t_col, now, zi_t, p_i, p_j_scalar,
@@ -232,8 +252,11 @@ def col_update(z_col, e_col, p_col, t_col, now, zi_t, p_i, p_j_scalar,
         return _pad1(x, Rp, fill).reshape(Rp // L, L)
 
     interp = backend == "pallas_interpret"
+    with jax.named_scope(LANE_PAD):
+        cols = (shp(z_col), shp(e_col), shp(p_col), shp(w_col), shp(t_col))
+        presyn = (shp(zi_t), shp(p_i))
     out = bcpnn_update.col_update_kernel_call(
-        shp(z_col), shp(e_col), shp(p_col), shp(w_col), shp(t_col), now,
-        shp(zi_t), shp(p_i), p_j_scalar, k=coeffs, eps=eps, bs=bs,
+        *cols, now, *presyn, p_j_scalar, k=coeffs, eps=eps, bs=bs,
         interpret=interp)
-    return tuple(o.reshape(Rp)[:R] for o in out)
+    with jax.named_scope(LANE_PAD):
+        return tuple(o.reshape(Rp)[:R] for o in out)
