@@ -358,12 +358,10 @@ def worklist_col_dispatch(kernel, fused_cols, h_idx, j_idx, t,
         return lambda hc: _column_worklist(hc, h_idx, j_idx, t, p,
                                            backend=kernel, fused=fused_cols,
                                            layout=lay)
-    # the column megakernel selects the per-entry presynaptic lane out of
-    # one 128-wide tile, so a fired batch larger than a lane tile falls
-    # back to the batched-view kernel (n_hcu >= ~366 at the default
-    # cap_fire formula) instead of tracing an unsatisfiable kernel
-    if fused_cols and h_idx.shape[0] <= ops.bcpnn_update.DEFAULT_BLOCK_L \
-            and (lay is None or lay.tpu_degenerate):
+    # the column megakernel takes a fired batch of any capacity; the staged
+    # form (fused_cols=False) and blocked layouts off the TPU-degenerate
+    # point take the batched-view kernel
+    if fused_cols and (lay is None or lay.tpu_degenerate):
         return lambda hc: _column_worklist_megakernel(hc, h_idx, j_idx, t,
                                                       p, kb, n, lay=lay)
     return lambda hc: _column_batched_on_flat(hc, h_idx, j_idx, t, p,

@@ -423,8 +423,7 @@ def fused_row_update_kernel_call(zij, eij, pij, wij, tij, zi, ei, pi, ti,
 def _fused_col_kernel(rbase_ref, rstep_ref, jt_ref, jl_ref, now_ref, z_ref,
                       e_ref, p_ref, w_ref, t_ref, zi_ref, pi_ref, pj_ref,
                       zo_ref, eo_ref, po_ref, wo_ref, to_ref,
-                      *, k: DecayCoeffs, eps: float, bs: int, bl: int,
-                      kp: int):
+                      *, k: DecayCoeffs, eps: float, bs: int, bl: int):
     """Grid step (entry e, row-block rb) of the fused column phase: the
     (bs, bl) lane tile of the five ij planes containing rows
     [h*R + rb*bs, ...) of the entry's fired column (rbase_ref[e] and the
@@ -435,10 +434,11 @@ def _fused_col_kernel(rbase_ref, rstep_ref, jt_ref, jl_ref, now_ref, z_ref,
     alignment rules are satisfied without data-dependent sub-lane offsets
     (a (R, 1) block at a prefetched lane offset would not lower).
 
-    The per-entry presynaptic traces arrive as (bs, kp) tiles of the
-    lane-padded (R, kp) buffers; the entry's own lane is selected with a
-    second iota mask and a lane reduce. The postsynaptic P scalar comes
-    from SMEM. Validity arrives as
+    The per-entry presynaptic traces arrive as the (bs, bl) tile holding
+    entry e's lane (lane tile e // bl of the (R, K') buffers, K' the
+    capacity rounded up to whole lane tiles); the entry's own lane, e % bl,
+    is selected with a second mask and a lane reduce. The postsynaptic P
+    scalar comes from SMEM. Validity arrives as
     rstep_ref[e] (1 = valid): the caller pins every one of a padding
     entry's grid steps onto the dedicated junk row-block past the logical
     plane (rbase = HR/bs, rstep = 0), so a padding step can only ever
@@ -454,9 +454,10 @@ def _fused_col_kernel(rbase_ref, rstep_ref, jt_ref, jl_ref, now_ref, z_ref,
     now = now_ref[0]
     lane = jax.lax.broadcasted_iota(jnp.int32, (bs, bl), 1)
     hit = valid & (lane == jl)                              # (bs, bl) mask
-    # select the entry's presynaptic lane out of the (bs, kp) trace tiles
-    ent_lane = jax.lax.broadcasted_iota(jnp.int32, (bs, kp), 1)
-    sel = (ent_lane == e).astype(jnp.float32)
+    # select the entry's presynaptic lane out of its (bs, bl) trace tiles;
+    # bl is a power of two, so e % bl is a mask (a remainder costs the
+    # scalar core a sequence of ops every grid step)
+    sel = (lane == (e & (bl - 1))).astype(jnp.float32)
     zi = jnp.sum(zi_ref[...] * sel, axis=1, keepdims=True)  # (bs, 1)
     p_i = jnp.sum(pi_ref[...] * sel, axis=1, keepdims=True)
     dt = (now - t_ref[...]).astype(jnp.float32)
@@ -493,12 +494,13 @@ def fused_col_update_kernel_call(zij, eij, pij, wij, tij, row_base, row_step,
     never share a tile with a valid entry — see the kernel docstring). The
     grid is 2-D (entry, row-block), so VMEM holds only (bs, 128) tiles
     regardless of R (a human-scale R=10000 column does NOT fit VMEM as one
-    block). zi_cols/pi_cols (r, kp) are the per-entry presynaptic traces
-    at `now`, column-major and lane-padded to kp == 128 so their blocks
-    cover the lane dimension exactly; pj_e (K,) the per-entry postsynaptic
-    P scalar (SMEM). The five plane inputs alias the five outputs:
-    each grid step rewrites one (bs, 128) tile of the fired column in
-    place — O(fired columns x R/bs) tile DMAs per call, the minimum the
+    block). zi_cols/pi_cols (r, K') are the per-entry presynaptic
+    traces at `now`, column-major and lane-padded to whole lane tiles;
+    step (e, rb) reads their (bs, 128) tile at block (rb, e // 128), so
+    each step DMAs two trace tiles whatever K is. pj_e (K,) the per-entry
+    postsynaptic P scalar (SMEM). The five plane inputs alias the five
+    outputs: each grid step rewrites one (bs, 128) tile of the fired column
+    in place — O(fired columns x R/bs) tile DMAs per call, the minimum the
     128-lane tile granularity allows (the paper's §VI.D column budget, at
     hardware tile resolution). Data-dependent in-place tiles ->
     ("arbitrary", "arbitrary") dimension semantics, like the row worklist
@@ -507,11 +509,12 @@ def fused_col_update_kernel_call(zij, eij, pij, wij, tij, row_base, row_step,
     HRp, Cp = zij.shape
     K = row_base.shape[0]
     R_BS = r // bs
-    kp = zi_cols.shape[1]
-    tile = pl.BlockSpec((bs, DEFAULT_BLOCK_L),
+    L = DEFAULT_BLOCK_L
+    lane_bits = L.bit_length() - 1          # e // L as a shift, L = 2**7
+    tile = pl.BlockSpec((bs, L),
                         lambda e, rb, rbase, rstep, jt, jl, now:
                         (rbase[e] + rb * rstep[e], jt[e]))
-    ent_tile = pl.BlockSpec((bs, kp), lambda e, rb, *_: (rb, 0))
+    ent_tile = pl.BlockSpec((bs, L), lambda e, rb, *_: (rb, e >> lane_bits))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(K, R_BS),
@@ -521,8 +524,7 @@ def fused_col_update_kernel_call(zij, eij, pij, wij, tij, row_base, row_step,
     out_shape = [jax.ShapeDtypeStruct((HRp, Cp), jnp.float32)] * 4 \
         + [jax.ShapeDtypeStruct((HRp, Cp), jnp.int32)]
     fn = pl.pallas_call(
-        functools.partial(_fused_col_kernel, k=k, eps=eps, bs=bs,
-                          bl=DEFAULT_BLOCK_L, kp=kp),
+        functools.partial(_fused_col_kernel, k=k, eps=eps, bs=bs, bl=L),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=_FUSED_COL_ALIASES,

@@ -190,8 +190,9 @@ def fused_col_update(zij, eij, pij, wij, tij, h_idx, j_idx, now, zi_t, p_i,
     rerouted onto the junk row-block appended by the alignment padding, so
     a padding grid step can never clobber — or stale-overwrite — a fired
     column). zi_t/p_i (K, rows): per-entry presynaptic traces at `now`
-    (transposed here to column-major (rows, K), lane-padded); pj_sc (K,):
-    per-entry postsynaptic P.
+    (transposed here to column-major (rows, K), lane-padded to a multiple
+    of 128, so K may exceed one lane tile: each grid step reads the tile
+    holding its entry); pj_sc (K,): per-entry postsynaptic P.
     Returns the five updated (H*rows, C) planes.
     """
     backend = backend or default_backend()
@@ -207,7 +208,6 @@ def fused_col_update(zij, eij, pij, wij, tij, h_idx, j_idx, now, zi_t, p_i,
     bs = next(b for b in (bcpnn_update.DEFAULT_BLOCK_S, 4, 2, 1)
               if rows % b == 0)
     HRp = HR + bs
-    assert K <= L, "fired-batch capacity exceeds one lane tile"
     interp = backend == "pallas_interpret"
     valid = h_idx < n_hcu
     r_bs = rows // bs
@@ -221,7 +221,8 @@ def fused_col_update(zij, eij, pij, wij, tij, h_idx, j_idx, now, zi_t, p_i,
                   _pad2(tij, HRp, Cp, fill=0))
     lane_tile, lane = j_eff // L, j_eff % L
     with jax.named_scope(LANE_PAD):
-        presyn = (_pad2(zi_t.T, rows, L), _pad2(p_i.T, rows, L))
+        kp = _round_up(K, L)
+        presyn = (_pad2(zi_t.T, rows, kp), _pad2(p_i.T, rows, kp))
     out = bcpnn_update.fused_col_update_kernel_call(
         *planes, row_base, row_step, lane_tile, lane, now, *presyn,
         pj_sc, k=coeffs, eps=eps, r=rows, bs=bs, interpret=interp)

@@ -352,6 +352,44 @@ def test_fused_col_megakernel_padding_entries_are_noops():
                                    err_msg=f"plane {name}")
 
 
+@pytest.mark.parametrize("C", [70, 100])
+def test_fused_col_megakernel_batch_past_one_lane_tile(C):
+    """A fired batch of 200 slots spans two lane tiles of the presynaptic
+    trace buffers: valid entries on both sides of slot 128 read their own
+    lane of their own tile, and the padding entries between them (poisoned
+    with (h, j) pairs that alias fired and unfired columns) stay no-ops;
+    every untouched cell stays EXACTLY preserved."""
+    rng = np.random.default_rng(C)
+    H_, R, cap = 6, 16, 200
+    a = _fused_col_args(rng, H_, R, C, cap, [])
+    slots = {3: (0, 5), 127: (1, C - 1), 128: (2, 0), 130: (3, 64),
+             199: (5, 5)}
+    h_idx = np.full(cap, H_, np.int32)
+    j_idx = rng.integers(0, C, cap).astype(np.int32)
+    j_idx[rng.integers(0, cap, 40)] = 5           # alias a fired column
+    for e, (h, j) in slots.items():
+        h_idx[e], j_idx[e] = h, j
+    a["h_idx"], a["j_idx"] = jnp.asarray(h_idx), jnp.asarray(j_idx)
+    out = ops.fused_col_update(
+        a["zij"], a["eij"], a["pij"], a["wij"], a["tij"],
+        h_idx=a["h_idx"], j_idx=a["j_idx"], now=a["now"],
+        zi_t=a["zi_t"], p_i=a["p_i"], pj_sc=a["pj_sc"],
+        coeffs=K, eps=EPS, n_hcu=H_, rows=R,
+        backend="pallas_interpret")
+    exp = _fused_col_expected(a, H_, R, cap)
+    touched = np.zeros((H_ * R, C), bool)
+    for h, j in slots.values():
+        touched[h * R:(h + 1) * R, j] = True
+    for o, ex, name in zip(out, exp, "zepwt"):
+        o = np.asarray(o)
+        np.testing.assert_allclose(o, ex, rtol=3e-6, atol=3e-6,
+                                   err_msg=f"plane {name}")
+        np.testing.assert_array_equal(o[~touched], ex[~touched],
+                                      err_msg=f"untouched cells, plane {name}")
+    # every fired column was rewritten: its Tij carries the `now` stamp
+    assert (np.asarray(out[4])[touched] == a["now"]).all()
+
+
 def test_fused_megakernel_sentinel_slots_are_noops():
     """Interleaved sentinel slots (slot order, no compaction) must leave
     every plane row and i-vector cell untouched, and emit zero weight rows

@@ -105,3 +105,16 @@ def test_kernel_compiles_for_v5e(kernel, scale, one_chip, no_cache):
             for s, d in _shapes(p, n)[kernel]]
     compiled = jax.jit(_call(kernel, p, n)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_col_compiles_for_v5e_past_one_lane_tile(one_chip, no_cache):
+    """The column megakernel at the benchmark's rodent cell, 1,152 HCUs: a
+    404-slot fired batch, so the presynaptic traces span four lane tiles
+    (block (rb, e // 128)) and the SMEM and prefetch arrays hold 404
+    entries."""
+    p, n = rodent_scale(1152), 1152
+    shapes = _shapes(p, n)["fused_col"]
+    assert shapes[5] == ((404,), jnp.int32)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(_call("fused_col", p, n)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -28,6 +28,7 @@ from hypothesis_compat import given, settings, st
 from repro.core import (init_network, make_connectivity, network_run,
                         test_scale as tiny_scale)
 from repro.core import hcu as H
+from repro.core import network as N
 from repro.core import worklist as WL
 from repro.core.params import BCPNNParams
 
@@ -380,22 +381,63 @@ def test_pallas_interpret_fused_col_megakernel_matches_vmap_path():
     _assert_bitwise(sa, fa, sb, fb)
 
 
-def test_fused_cols_megakernel_large_fired_batch_fallback():
-    """A fired-batch capacity larger than one lane tile (cap_fire > 128)
-    cannot use the column megakernel (its per-entry lane select is one
-    128-wide tile): `engine.worklist_col_dispatch` must fall back to the
-    batched-view kernel instead of tracing an unsatisfiable kernel, still
-    bitwise against the vmapped path."""
-    ext = _ext_tensor(LAZY_P, seed=3, n_ticks=6, lam=3.0)
+# 300 HCUs, so the fired batch can hold more than one lane tile's 128
+# entries; at out_rate 0.5 about 150 HCUs fire a tick, so valid entries sit
+# past slot 128
+WIDE_P = BCPNNParams(n_hcu=300, rows=16, cols=16, fanout=8, active_queue=8,
+                     max_delay=8, out_rate=0.5)
+
+
+def _pallas_kernels(jaxpr):
+    """(kernel function name, grid) of every pallas_call in a jaxpr, nested
+    jaxprs (scan, cond, pjit bodies) included."""
+    found = []
+    for eq in jaxpr.eqns:
+        if eq.primitive.name == "pallas_call":
+            found.append((eq.params["jaxpr"].debug_info.func_name,
+                          tuple(eq.params["grid_mapping"].grid)))
+        for v in eq.params.values():
+            for x in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(x, "jaxpr", x)
+                if hasattr(sub, "eqns"):
+                    found += _pallas_kernels(sub)
+    return found
+
+
+@pytest.mark.parametrize("cap,xr", [(130, None), (300, None), (130, 7)])
+def test_fused_cols_megakernel_large_fired_batch_fallback(cap, xr):
+    """A fired-batch capacity larger than one lane tile (cap_fire > 128; 300
+    spans three tiles) takes the column megakernel too, on the flat planes
+    and on a TPU-degenerate blocked layout (xr=7: row padding): the chunk
+    holds the `_fused_col_kernel` call over cap entries and no batched
+    column kernel, and the trajectory (drops at cap 130 included) stays
+    bitwise against the vmapped pallas-interpret path."""
+    from repro.core import layout as L
+    p = WIDE_P
+    lay = None if xr is None else L.BlockedLayout(rows=p.rows, cols=p.cols,
+                                                  xr=xr, xc=128)
+    assert lay is None or lay.tpu_degenerate
+    ext = _ext_tensor(p, seed=3, n_ticks=2, lam=3.0)
     key = jax.random.PRNGKey(0)
-    conn = make_connectivity(LAZY_P, jax.random.fold_in(key, 1))
-    cap = 130
-    sa, fa = network_run(init_network(LAZY_P, key), conn, ext, LAZY_P,
-                         chunk=6, worklist=False, cap_fire=cap,
-                         backend="pallas_interpret")
-    sb, fb = network_run(init_network(LAZY_P, key), conn, ext, LAZY_P,
-                         chunk=6, worklist=True, fused_cols=True,
-                         cap_fire=cap, backend="pallas_interpret")
+    conn = make_connectivity(p, jax.random.fold_in(key, 1))
+    kw = dict(chunk=2, cap_fire=cap, backend="pallas_interpret")
+    chunk = jax.make_jaxpr(lambda s, c, e: N._run_chunk(
+        s, c, e, p, eager=False, merged=False, backend="pallas_interpret",
+        cap_fire=cap, worklist=True, fused=None, fused_cols=True,
+        layout=lay))(init_network(p, key, layout=lay), conn, ext)
+    kernels = dict(_pallas_kernels(chunk.jaxpr))
+    rows = p.rows if lay is None else lay.padded_rows
+    bs = next(b for b in (8, 4, 2, 1) if rows % b == 0)
+    assert kernels.get("_fused_col_kernel") == (cap, rows // bs), kernels
+    assert "_col_kernel" not in kernels, kernels
+    sa, fa = network_run(init_network(p, key), conn, ext, p, worklist=False,
+                         **kw)
+    sb, fb = network_run(init_network(p, key, layout=lay), conn, ext, p,
+                         worklist=True, fused_cols=True, layout=lay, **kw)
+    if lay is not None:
+        sb = sb._replace(hcus=L.load_hcus(sb.hcus, lay))
+    assert ((np.asarray(fa) >= 0).sum(axis=1) > 128).all(), \
+        "case must put valid entries past slot 128"
     _assert_bitwise(sa, fa, sb, fb)
 
 
