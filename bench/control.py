@@ -4,7 +4,8 @@
 
 For each seed, in one process that owns the cell's chips: set the cell up
 and run a window of `--seconds` exactly as `run.py` does, then replay the
-run in the reference twice, teacher-forced on the system's fired history:
+run in the reference twice, chip by chip as `run.py` does, teacher-forced
+on the system's fired history:
 
   * in float32, the precision the configuration states: the system's
     readings (`state_err`, `wta_gap`, `fire_mismatch` against it), which
@@ -32,37 +33,41 @@ import harness  # noqa: E402
 NUMBERS = ("state_err", "wta_gap", "fire_mismatch")
 
 
-def host_pieces(st, m, dev0, block: int = 128):
-    """`pieces` for `reference.compare_states` of a reference state held on
-    the host, moved to `dev0` a block of HCUs at a time."""
+def host_pieces(held, shares, devs, m, block: int = 128):
+    """`pieces` for `reference.compare_states` of reference shares held on
+    the host, each against the share `shares[d]` on its chip, moved there
+    a block of HCUs at a time."""
     import jax
     import reference as ref
-    block = min(block, m.n_hcu)
-    for h0 in range(0, m.n_hcu, block):
-        out = {}
-        for k in ref.LEAVES:
-            per = 1 if k in ("zj", "ej", "pj", "h") else m.rows
-            out[k] = jax.device_put(
-                getattr(st, k)[h0 * per:(h0 + block) * per], dev0)
-        yield h0, out
+    h = m.n_hcu // len(devs)
+    block = min(block, h)
+    for st, ref_st, dev in zip(held, shares, devs, strict=True):
+        for h0 in range(0, h, block):
+            out = {}
+            for k in ref.LEAVES:
+                per = 1 if k in ref.HCU_LEAVES else m.rows
+                out[k] = jax.device_put(
+                    getattr(st, k)[h0 * per:(h0 + block) * per], dev)
+            yield out, ref_st, h0
 
 
-def readings(r: "harness.Run", dev0) -> dict:
+def readings(r: "harness.Run") -> dict:
     """{"system": {...}, "control": {...}} of one set-up run whose window
-    has closed. The control's final state waits on the host while the
-    float32 replay runs, so that the two fit one chip beside the system's."""
+    has closed. The control's final shares wait on the host while the
+    float32 replay runs, so that the two fit each chip beside the
+    system's share."""
     import jax
     import jax.numpy as jnp
     import reference as ref
 
     fired = r.history()
-    args = (r.m, r.conn, r.ext, fired, r.seed, r.chunk, dev0)
+    args = (r.m, r.conn, r.ext, fired, r.seed, r.chunk, r.devs)
     st_b, _, own_b = harness.replay(*args, dtype=jnp.bfloat16)
     st_b = jax.device_get(st_b)
-    st, stats, _ = harness.replay(*args, probes=(fired, own_b))
-    sys_err = ref.compare_states(r.m, r.prog.pieces(dev0), st).worst()
-    ctl_err = ref.compare_states(r.m, host_pieces(st_b, r.m, dev0),
-                                 st).worst()
+    shares, stats, _ = harness.replay(*args, probes=(fired, own_b))
+    sys_err = ref.compare_states(r.m, r.prog.pieces(shares)).worst()
+    ctl_err = ref.compare_states(
+        r.m, host_pieces(st_b, shares, r.devs, r.m)).worst()
     ctl_gate = int((((own_b >= 0) != (fired >= 0))).sum())
     return {"system": {"state_err": sys_err[1],
                        "wta_gap": float(stats["gaps"][:, 0].max()),
@@ -93,7 +98,7 @@ def main(argv=None, root: Path | None = None) -> int:
         t0 = time.perf_counter()
         r = harness.Run(c, seed, devs)
         r.window(args.seconds)
-        out = {"seed": seed, **readings(r, devs[0]),
+        out = {"seed": seed, **readings(r),
                "seconds": time.perf_counter() - t0}
         print(json.dumps(out), flush=True)
         rows.append(out)

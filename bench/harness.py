@@ -12,20 +12,25 @@ returns a number or None. This file holds nothing specific to one cell.
 A run, in one process that owns the cell's chips:
 
   set-up   check for the chips and the Pallas kernel backend; build the
-           network on the device from the seed (`Simulator(p, key=seed)`,
-           fan-out drawn from the seed by `connectivity`); stage the
-           external input on the device in chunks; run two warm-up chunks
-           through the same call the window uses (they compile, or load
-           from the compile cache kept in `.jax_cache/` of the checkout
-           unless JAX_COMPILATION_CACHE_DIR is set).
+           network from the seed (`Simulator(p, key=seed)`, fan-out drawn
+           from the seed by `connectivity` on the first chip and held on
+           the host): on the chip for one chip, on the host for several
+           (handed over as NumPy), whose shares `run_sharded` then puts
+           each on its own chip; stage the external input on the device
+           in chunks; run two warm-up chunks through the same call the
+           window uses (they compile, or load from the compile cache
+           kept in `.jax_cache/` of the checkout unless
+           JAX_COMPILATION_CACHE_DIR is set).
   window   dispatch chunks, one in flight, until `--seconds` have passed;
            sim_ms_per_s is every simulated ms over all of the window's
            wall time. With --trace 1 the window is traced instead and the
            per-layer metrics are read from the trace.
   check    read the devices' peak memory, then replay every tick of the run
            in the plain reference (`reference.py`), teacher-forced on the
-           fired history the system produced, and compare the flushed
-           state and the WTA's choices against the configuration's limits.
+           fired history the system produced, chip by chip: each chip
+           replays its own HCUs and compares them with its own share of
+           the system's flushed state; the WTA's choices too, against the
+           configuration's limits.
 
 The last line of stdout is the result's JSON object; the compared numbers
 and their limits are also the last lines of stderr.
@@ -33,6 +38,7 @@ and their limits are also the last lines of stderr.
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib.util
 import json
 import math
@@ -153,7 +159,10 @@ def require_chips(n: int):
 def connectivity(m, seed: int):
     """Fan-out of every (HCU, column): `fanout` targets uniform over HCUs
     and rows, delays 1 + Geometric(1 / (mean_delay - 1)) clipped to
-    [1, max_delay - 1] (the model's dimensioning, eBrainII sec. IV)."""
+    [1, max_delay - 1] (the model's dimensioning, eBrainII sec. IV). Drawn
+    on the default device, so that its values are those of the chip, and
+    returned as NumPy: no chip holds the whole network's fan-out but for
+    the check, which puts a copy on each."""
     import jax
     import jax.numpy as jnp
 
@@ -168,7 +177,8 @@ def connectivity(m, seed: int):
         dl = jnp.clip(1 + geo.astype(jnp.int32), 1, m.max_delay - 1)
         return dh, dr, dl
 
-    return make(jax.random.fold_in(jax.random.PRNGKey(seed), 0xC0))
+    return jax.device_get(
+        make(jax.random.fold_in(jax.random.PRNGKey(seed), 0xC0)))
 
 
 class Program:
@@ -177,14 +187,25 @@ class Program:
 
     def __init__(self, params: dict, seed: int, devs, conn):
         import jax
+        import numpy as np
         from repro.core import Simulator
         from repro.core.params import BCPNNParams
         self.p = BCPNNParams(**params)
         self.devs = devs
-        self.sim = Simulator(self.p, key=seed)
+        if len(devs) == 1:
+            self.mesh = None
+            self.sim = Simulator(self.p, key=seed)
+            conn = jax.device_put(conn, devs[0])
+        else:
+            # built on the host and handed over as NumPy: `run_sharded`
+            # puts each chip's share on its chip, and no chip holds the
+            # whole network (host-backed jax arrays pass through the first
+            # chip: 2.84 GB more there at 4 x 1,152 rodent HCUs)
+            self.mesh = jax.sharding.Mesh(devs, ("hcu",))
+            with jax.default_device(jax.devices("cpu")[0]):
+                self.sim = Simulator(self.p, key=seed)
+            self.sim.state = jax.tree.map(np.asarray, self.sim.state)
         self.sim.conn = type(self.sim.conn)(*conn)
-        self.mesh = (None if len(devs) == 1
-                     else jax.sharding.Mesh(devs, ("hcu",)))
 
     def place(self, ext):
         """Stage one chunk of external rows where the call expects it."""
@@ -200,24 +221,29 @@ class Program:
         return self.sim.run_sharded(ext, mesh=self.mesh)
 
     def drops(self) -> int:
-        return int(sum(self.sim.drops().values()))
+        """Spikes dropped in the run. On several chips each chip counts its
+        own overflows in counters that the program declares replicated,
+        so every chip's own copy is read and the copies summed."""
+        if self.mesh is None:
+            return int(sum(self.sim.drops().values()))
+        st = self.sim.state
+        return sum(int(s.data) for a in (st.drops_in, st.drops_fire,
+                                         st.drops_route) if a is not None
+                   for s in a.addressable_shards)
 
-    def pieces(self, dev0):
-        """The system's raw state for `reference.compare_states`: one
-        (first HCU, leaves) pair per device, moved to the reference's
-        device (the flat (H*R, C) planes are the reference's layout)."""
-        import jax
+    def pieces(self, shares):
+        """`pieces` for `reference.compare_states`: chip d's share of the
+        system's raw state, its HCUs [d*h, (d+1)*h) as they lie on the
+        chip (the flat (H*R, C) planes are the reference's layout), beside
+        the reference's share `shares[d]` on the same chip."""
         hc = self.sim.state.hcus
         names = dict(z="zij", e="eij", p="pij", t="tij", zi="zi", ei="ei",
                      pi="pi", ti="ti", zj="zj", ej="ej", pj="pj", h="h")
-        for d in range(len(self.devs)):
-            leaves = {}
-            for k, f in names.items():
-                a = getattr(hc, f)
-                sh = sorted(a.addressable_shards,
-                            key=lambda s: s.index[0].start or 0)[d]
-                leaves[k] = jax.device_put(sh.data, dev0)
-            yield d * (self.p.n_hcu // len(self.devs)), leaves
+        for dev, ref_st in zip(self.devs, shares, strict=True):
+            leaves = {k: next(s.data for s in getattr(hc, f).addressable_shards
+                              if s.device == dev)
+                      for k, f in names.items()}
+            yield leaves, ref_st, 0
 
 
 def peak_memory(devs) -> int:
@@ -228,50 +254,65 @@ def peak_memory(devs) -> int:
     return max(vals)
 
 
-def replay(m, conn, ext_np, forced, seed: int, chunk: int, dev0,
+def replay(m, conn, ext_np, forced, seed: int, chunk: int, devs,
            dtype=None, probes=()):
     """Replay every tick of `forced` (the fired history, (T, H)) in the
-    reference on `dev0`, teacher-forced on it, reading the WTA gap of each
-    history in `probes`. Returns (final state, per-tick stats as NumPy,
-    the reference's own winners (T, H))."""
+    reference, teacher-forced on it, reading the WTA gap of each history
+    in `probes`: chip d of `devs` replays its own HCUs [d*h, (d+1)*h),
+    with its own copy of the fan-out `conn` and the whole history. Every
+    chip's chunk is sent before any is waited for. Returns (each chip's
+    final reference share, per-tick stats over all chips as NumPy, the
+    reference's own winners (T, H))."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     import reference as ref
 
     dtype = dtype or jnp.float32
-    T = forced.shape[0]
+    T, n = forced.shape[0], len(devs)
+    h = m.n_hcu // n
     nb = ext_np.shape[0] // chunk
-    put = lambda x: jax.device_put(x, dev0)
-    with jax.default_device(dev0):
-        st = ref.init_state(m, dtype)
-        ext = [put(ext_np[i * chunk:(i + 1) * chunk]) for i in range(nb)]
-        bkey = ref.base_key(seed)
-        stats, own = [], []
-        for k in range(T // chunk):
-            sl = slice(k * chunk, (k + 1) * chunk)
-            fk = put(forced[sl])
-            pr = put(np.stack([q[sl] for q in probes]) if probes else
-                     np.zeros((0, chunk, m.n_hcu), np.int32))
-            st, (o, s) = ref.replay_chunk(st, conn, ext[k % nb], fk, pr,
-                                          bkey, m=m, dtype=dtype,
-                                          n_probes=len(probes))
-            stats.append(s)
-            own.append(o)
-    stats = {k: np.concatenate([np.asarray(s[k]) for s in stats])
-             for k in stats[0]}
-    return st, stats, np.concatenate([np.asarray(o) for o in own])
+    shares = []
+    for d, dev in enumerate(devs):
+        put = functools.partial(jax.device_put, device=dev)
+        with jax.default_device(dev):
+            shares.append(dict(
+                put=put, st=ref.init_state(m, dtype, n),
+                conn=put(conn), bkey=ref.base_key(seed), stats=[], own=[],
+                ext=[put(ext_np[i * chunk:(i + 1) * chunk, d * h:(d + 1) * h])
+                     for i in range(nb)]))
+    for k in range(T // chunk):
+        sl = slice(k * chunk, (k + 1) * chunk)
+        for d, sh in enumerate(shares):
+            cols = slice(d * h, (d + 1) * h)
+            pr = sh["put"](np.stack([q[sl, cols] for q in probes])
+                           if probes else np.zeros((0, chunk, h), np.int32))
+            sh["st"], (o, s) = ref.replay_chunk(
+                sh["st"], sh["conn"], sh["ext"][k % nb],
+                sh["put"](forced[sl]), pr, sh["bkey"], m=m, dtype=dtype,
+                n_probes=len(probes), part=(d, n))
+            sh["stats"].append(s)
+            sh["own"].append(o)
+    per_chip = [{k: np.concatenate([np.asarray(s[k]) for s in sh["stats"]])
+                 for k in sh["stats"][0]} for sh in shares]
+    stats = {k: (np.max if k == "gaps" else np.sum)(
+        [c[k] for c in per_chip], axis=0) for k in per_chip[0]}
+    own = np.concatenate([np.concatenate([np.asarray(o) for o in sh["own"]])
+                          for sh in shares], axis=1)
+    return [sh["st"] for sh in shares], stats, own
 
 
-def check(m, pieces, conn, ext_np, fired, seed: int, chunk: int, dev0,
+def check(m, prog, conn, ext_np, fired, seed: int, chunk: int, devs,
           limits: dict):
-    """Replay the whole run in the reference on `dev0` and compare. Returns
-    (checks {name: {value, limit}}, per-tick stats)."""
+    """Replay the whole run in the reference chip by chip and compare each
+    chip's share of the system (`prog.pieces`) with the reference's share
+    on the same chip. Returns (checks {name: {value, limit}}, per-tick
+    stats)."""
     import reference as ref
 
-    st, stats, _ = replay(m, conn, ext_np, fired, seed, chunk, dev0,
-                          probes=(fired,))
-    errs = ref.compare_states(m, pieces, st)
+    shares, stats, _ = replay(m, conn, ext_np, fired, seed, chunk, devs,
+                              probes=(fired,))
+    errs = ref.compare_states(m, prog.pieces(shares))
     log("state error per field: " + ", ".join(
         f"{k}={v:.3e}" for k, v in errs.per_field().items()))
     values = {"state_err": errs.worst()[1],
@@ -279,6 +320,12 @@ def check(m, pieces, conn, ext_np, fired, seed: int, chunk: int, dev0,
               "fire_mismatch": int(stats["mismatch"].sum())}
     checks = {k: {"value": v, "limit": limits[k]} for k, v in values.items()}
     return checks, stats
+
+
+def attempted(stats, m) -> int:
+    """Spikes the run offered: the external rows and the fired batch's
+    fan-out."""
+    return int(stats["n_ext"].sum() + stats["n_fired"].sum() * m.fanout)
 
 
 def passed(checks: dict) -> bool:
@@ -417,14 +464,13 @@ def run(args, root: Path, t_start: float) -> dict:
     fired = r.history()
     failed = r.prog.drops()
     t_check = time.perf_counter()
-    checks, stats = check(m, r.prog.pieces(dev0), r.conn, r.ext, fired,
-                          args.seed, r.chunk, dev0, cfg["limits"])
+    checks, stats = check(m, r.prog, r.conn, r.ext, fired,
+                          args.seed, r.chunk, devs, cfg["limits"])
     log(f"check {time.perf_counter() - t_check:.3f} s")
-    attempted = int(stats["n_ext"].sum() + stats["n_fired"].sum() * m.fanout)
 
     device = {"platform": dev0.platform, "kind": kind, "count": len(devs),
               "memory_peak_bytes": hbm}
-    result = {"correct": passed(checks), "attempted": attempted,
+    result = {"correct": passed(checks), "attempted": attempted(stats, m),
               "failed": failed}
     if not tdir:
         values = {"sim_ms_per_s": (ticks[1] - ticks[0]) * m.dt_ms / window_s,

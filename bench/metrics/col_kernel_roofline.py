@@ -4,9 +4,8 @@ Roofline time of the column work the traced ticks needed
 (`work.col_kernel_work` on the fired columns of the fired batch, R cells
 each) over the summed device time, on every chip, of
 `fused_col_update_kernel_call`, the megakernel that does the whole column
-update where the fired batch fits one lane tile (at most 128 entries). A
-larger batch takes the batched kernel behind XLA gathers and scatters,
-which this metric does not read: then None."""
+update for a fired batch of any size (a batch over one lane tile reads
+its presynaptic traces a tile at a time). None where no such op ran."""
 import work
 import xtrace
 

@@ -14,7 +14,8 @@ and importing nothing of the system under test:
   5. for every fired (HCU, column), bring the column's cells to `t` and add
      the presynaptic Z increment, then bump the fired column's Zj;
   6. fan the spikes out into the delay queues (capacity per bucket, overflow
-     counted as a drop).
+     counted as a drop). The exchange between devices is not modelled: a
+     spike that a route of the system drops shows as a state error.
 
 The Z -> E -> P cascade between events has the exact solution used in steps
 3 and 5 (`decay`). The synaptic state is kept as (H*R, Cp) planes, row
@@ -25,8 +26,15 @@ a plane of any other width makes the TPU compiler copy the whole plane.
 
 The reference is teacher-forced: it follows the fired history the system
 produced, so one rounding-level near-tie in the WTA cannot make the two
-trajectories part. What it checks of the WTA is the gap by which each fired
-column's Gumbel-perturbed support lies below the best one (`jax.random
+trajectories part. That also lets it run as shares: share d of n replays
+HCUs [d*H/n, (d+1)*H/n) alone, on a chip of its own. What a share needs of
+the other HCUs is global and cheap, and each share computes it over all
+HCUs from the fired history: the fired batch (each system device's first
+`cap_fire` fired HCUs) and its fan-out, in fired-batch then fan-out order.
+It keeps only its own destinations of that fan-out, its own fired columns,
+its own delay queues and planes; one share is the whole network.
+
+What it checks of the WTA is the gap by which each fired column's Gumbel-perturbed support lies below the best one (`jax.random
 .categorical` is argmax(logits + Gumbel)), drawn from the same seeded key
 chain: key(seed) -> fold_in(0x5EED) -> fold_in(t) -> fold_in(global HCU id)
 -> split into (gate, winner) keys. The gate (fire with probability
@@ -111,10 +119,11 @@ class State(NamedTuple):
     drops: jnp.ndarray    # () int32 delay-queue overflows
 
 
-def init_state(m: Model, dtype=jnp.float32) -> State:
-    """The network before its first tick (every HCU alike), its values
-    rounded to `dtype`."""
-    H, R, C, D, A = m.n_hcu, m.rows, m.cols, m.max_delay, m.active_queue
+def init_state(m: Model, dtype=jnp.float32, parts: int = 1) -> State:
+    """One of `parts` equal shares of the network before its first tick
+    (every HCU alike), its values rounded to `dtype`."""
+    H, R, C, D, A = (m.n_hcu // parts, m.rows, m.cols, m.max_delay,
+                     m.active_queue)
     Cp = m.lanes
     full = lambda s, v: jnp.full(s, jnp.asarray(v, dtype), jnp.float32)
     return State(
@@ -150,8 +159,9 @@ def weight(p_ij, p_i, p_j, eps):
     return jnp.log((p_ij + eps * eps) / ((p_i + eps) * (p_j + eps)))
 
 
-def wta_draws(base_key, t, n_hcu: int, cols: int):
-    """(gate uniform (H,), Gumbel noise (H, C)) of tick t for every HCU."""
+def wta_draws(base_key, t, first: int, n_hcu: int, cols: int):
+    """(gate uniform (n,), Gumbel noise (n, C)) of tick t for the `n_hcu`
+    HCUs from global id `first` on."""
     k_t = jax.random.fold_in(base_key, t)
 
     def one(g):
@@ -159,7 +169,7 @@ def wta_draws(base_key, t, n_hcu: int, cols: int):
         return (jax.random.uniform(k_gate),
                 jax.random.gumbel(k_win, (cols,), jnp.float32))
 
-    return jax.vmap(one)(jnp.arange(n_hcu, dtype=jnp.int32))
+    return jax.vmap(one)(first + jnp.arange(n_hcu, dtype=jnp.int32))
 
 
 def base_key(seed: int):
@@ -177,15 +187,32 @@ def _dedup(rows, R: int):
     return a, jnp.where(valid, counts, 0), valid
 
 
-def _tick(m: Model, dtype, st: State, conn, ext_t, forced_t, probes_t, bkey):
+def _rank_within(key):
+    """Each entry's rank among the entries of its key, in their order."""
+    order = jnp.argsort(key, stable=True)
+    ks = key[order]
+    pos = jnp.arange(ks.shape[0])
+    start = jax.lax.cummax(jnp.where(
+        jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]]), pos, 0))
+    return jnp.zeros_like(pos).at[order].set(pos - start)
+
+
+def _tick(m: Model, dtype, st: State, conn, ext_t, forced_t, probes_t, bkey,
+          part):
+    """One tick of share `part` = (d, n), HCUs [o, o + nh) with nh = H/n
+    and o = d*nh. `st`, `ext_t` and `probes_t` are the share's own; `conn`
+    and the fired history `forced_t` (H,) are the whole network's."""
     H, R, C, D, A, F = (m.n_hcu, m.rows, m.cols, m.max_delay,
                         m.active_queue, m.fanout)
+    d, n = part
+    nh = H // n
+    o = d * nh
     f = lambda x: jnp.asarray(x, dtype)
     lo = lambda x: x.astype(dtype)                 # stored f32 -> working
     f32 = lambda x: x.astype(jnp.float32)
     t = st.now + 1
     eps = f(m.eps)
-    hh = jnp.arange(H, dtype=jnp.int32)
+    hh = jnp.arange(nh, dtype=jnp.int32)
 
     # 1. this tick's bucket, cleared, plus the external rows
     b = t % D
@@ -201,7 +228,7 @@ def _tick(m: Model, dtype, st: State, conn, ext_t, forced_t, probes_t, bkey):
     a, cnt, valid = _dedup(rows, R)
     n_rows = jnp.sum(valid)
     gr = hh[:, None] * R + jnp.where(valid, a, 0)          # (H, S) rows
-    gw = jnp.where(valid, gr, H * R)                # out of range: dropped
+    gw = jnp.where(valid, gr, nh * R)                # out of range: dropped
     cntf = cnt.astype(dtype)
     zi, ei, pi = decay(lo(st.zi[gr]), lo(st.ei[gr]), lo(st.pi[gr]),
                        (t - st.ti[gr]).astype(dtype), m.tau_zi,
@@ -225,13 +252,13 @@ def _tick(m: Model, dtype, st: State, conn, ext_t, forced_t, probes_t, bkey):
     drive = jnp.sum(cntf[..., None] * w, axis=1)
     h = lo(st.h) * f(math.exp(-m.dt_ms / m.tau_m)) + drive
     s = h + jnp.log(pj + eps)
-    u, g = wta_draws(bkey, t, H, C)
+    u, g = wta_draws(bkey, t, o, nh, C)
     # argmax(logits + Gumbel), as jax.random.categorical samples; the
     # noise is drawn in float32 and added in the reference's precision
     score = (s / m.wta_temp + g.astype(dtype)).astype(jnp.float32)
     gate = u < m.out_rate * m.dt_ms
     own = jnp.where(gate, jnp.argmax(score, axis=1), -1).astype(jnp.int32)
-    fired = own if forced_t is None else forced_t
+    fired = forced_t[o:o + nh]
     mismatch = jnp.sum(gate != (fired >= 0))
     best = jnp.max(score, axis=1)
 
@@ -242,53 +269,56 @@ def _tick(m: Model, dtype, st: State, conn, ext_t, forced_t, probes_t, bkey):
     gaps = jnp.stack([gap(j) for j in probes_t]) if probes_t else \
         jnp.zeros((0,), jnp.float32)
 
-    # the fired batch: the first cap_fire fired HCUs of each device, in order
-    is_f = (fired >= 0).reshape(m.n_dev, -1)
+    # the fired batch: the first cap_fire fired HCUs of each device, in
+    # order, over the whole network (hk, jk) and this share's part of it
+    # (hl, jl), local ids
+    is_f = (forced_t >= 0).reshape(m.n_dev, -1)
     rank = jnp.cumsum(is_f, axis=1) - 1
     capped = (is_f & (rank < m.cap_fire)).reshape(-1)
     kmax = m.cap_fire * m.n_dev
     hk = jnp.nonzero(capped, size=kmax, fill_value=H)[0].astype(jnp.int32)
     ok = hk < H
-    jk = jnp.where(ok, fired[jnp.minimum(hk, H - 1)], 0)
-    n_fired = jnp.sum(ok)
+    jk = jnp.where(ok, forced_t[jnp.minimum(hk, H - 1)], 0)
+    hl = jnp.nonzero(capped[o:o + nh], size=kmax // n,
+                     fill_value=nh)[0].astype(jnp.int32)
+    okl = hl < nh
+    jl = jnp.where(okl, fired[jnp.minimum(hl, nh - 1)], 0)
+    n_fired = jnp.sum(okl)
 
-    # 5. column updates of the fired batch
-    hc = jnp.minimum(hk, H - 1)
+    # 5. column updates of the share's fired columns
+    hc = jnp.minimum(hl, nh - 1)
     gc = hc[:, None] * R + jnp.arange(R)[None, :]           # (K, R) rows
     zi_c, _, pi_c = decay(lo(st.zi[gc]), lo(st.ei[gc]), lo(st.pi[gc]),
                           (t - st.ti[gc]).astype(dtype), m.tau_zi,
                           m.tau_e, m.tau_p)
-    ix = (gc, jk[:, None])
+    ix = (gc, jl[:, None])
     z, e, p = decay(lo(st.z[ix]), lo(st.e[ix]), lo(st.p[ix]),
                     (t - st.t[ix]).astype(dtype), m.tau_zij, m.tau_e,
                     m.tau_p)
     z = z + zi_c
-    iw = (jnp.where(ok[:, None], gc, H * R), jk[:, None])
+    iw = (jnp.where(okl[:, None], gc, nh * R), jl[:, None])
     putc = lambda plane, v: plane.at[iw].set(v.astype(plane.dtype),
                                              mode="drop")
     st = st._replace(z=putc(st.z, z), e=putc(st.e, e), p=putc(st.p, p),
                      t=putc(st.t, jnp.broadcast_to(t, z.shape)))
-    zj = lo(f32(zj).at[hk, jk].add(1.0, mode="drop"))
+    zj = lo(f32(zj).at[hl, jl].add(1.0, mode="drop"))
 
-    # 6. fan-out into the delay queues, in fired-batch then fan-out order
-    dh = conn[0][hc, jk].reshape(-1)
-    dr = conn[1][hc, jk].reshape(-1)
-    dl = conn[2][hc, jk].reshape(-1)
-    mv = jnp.repeat(ok, F)
+    # 6. fan-out of the whole fired batch, in fired-batch then fan-out
+    # order, into the delay queues of this share's HCUs
+    hg = jnp.minimum(hk, H - 1)
+    dh = conn[0][hg, jk].reshape(-1)
+    dr = conn[1][hg, jk].reshape(-1)
+    dl = conn[2][hg, jk].reshape(-1)
+    mv = jnp.repeat(ok, F) & (dh >= o) & (dh < o + nh)
+    dh = dh - o
     bucket = (t + dl) % D
-    key = jnp.where(mv, dh * D + bucket, H * D)
-    order = jnp.argsort(key, stable=True)
-    ks = key[order]
-    pos = jnp.arange(ks.shape[0])
-    start = jax.lax.cummax(jnp.where(
-        jnp.concatenate([jnp.ones((1,), bool), ks[1:] != ks[:-1]]), pos, 0))
-    rank_m = jnp.zeros_like(pos).at[order].set(pos - start)
-    slot = q_count[jnp.minimum(dh, H - 1), bucket] + rank_m
+    key = jnp.where(mv, dh * D + bucket, nh * D)
+    slot = q_count[jnp.clip(dh, 0, nh - 1), bucket] + _rank_within(key)
     keep = mv & (slot < A)
-    q_rows = q_rows.at[jnp.where(keep, dh, H), bucket, slot].set(
+    q_rows = q_rows.at[jnp.where(keep, dh, nh), bucket, slot].set(
         dr, mode="drop")
-    arrivals = jnp.zeros((H, D), jnp.int32).at[jnp.where(mv, dh, H),
-                                               bucket].add(1, mode="drop")
+    arrivals = jnp.zeros((nh, D), jnp.int32).at[jnp.where(mv, dh, nh),
+                                                bucket].add(1, mode="drop")
     new_count = jnp.minimum(q_count + arrivals, A)
     drops = st.drops + jnp.sum(q_count + arrivals - new_count)
 
@@ -300,24 +330,27 @@ def _tick(m: Model, dtype, st: State, conn, ext_t, forced_t, probes_t, bkey):
     return st, own, stats
 
 
-@functools.partial(jax.jit, static_argnames=("m", "dtype", "n_probes"),
+@functools.partial(jax.jit,
+                   static_argnames=("m", "dtype", "n_probes", "part"),
                    donate_argnums=(0,))
 def replay_chunk(st: State, conn, ext, forced, probes, bkey, *, m: Model,
-                 dtype, n_probes: int):
-    """Advance the reference len(ext) ticks. ext (T, H, W) external rows;
-    forced (T, H) the fired history to follow, or None to follow its own
-    WTA; probes (P, T, H) winners whose gap is read. Returns
-    (state', own winners (T, H), per-tick stats)."""
+                 dtype, n_probes: int, part=(0, 1)):
+    """Advance share `part` = (d, n) of the reference len(ext) ticks
+    (`_tick`). ext (T, h, W) the share's external rows; forced (T, H) the
+    whole fired history to follow; probes (P, T, h) the share's winners
+    whose gap is read. Returns (state', the share's own winners (T, h),
+    the share's per-tick stats)."""
     def body(s, xs):
         e, fo, pr = xs
         s, own, stats = _tick(m, dtype, s, conn, e, fo,
-                              tuple(pr[i] for i in range(n_probes)), bkey)
+                              tuple(pr[i] for i in range(n_probes)), bkey,
+                              part)
         return s, (own, stats)
 
     if n_probes:
         pr = jnp.moveaxis(probes, 0, 1)
     else:
-        pr = jnp.zeros((ext.shape[0], 0, m.n_hcu), jnp.int32)
+        pr = jnp.zeros((ext.shape[0], 0, m.n_hcu // part[1]), jnp.int32)
     return jax.lax.scan(body, st, (ext, forced, pr))
 
 
@@ -329,6 +362,7 @@ FIELDS = ("z", "e", "p", "w", "zi", "ei", "pi", "zj", "ej", "pj", "h")
 
 
 LEAVES = ("z", "e", "p", "t", "zi", "ei", "pi", "ti", "zj", "ej", "pj", "h")
+HCU_LEAVES = ("zj", "ej", "pj", "h")        # one row per HCU, not R
 
 
 def flush(lv: dict, now, m: Model) -> dict:
@@ -359,7 +393,7 @@ def block_errors(sys: dict, ref: State, s0, r0, *, m: Model, hb: int):
         onward of the per-HCU ones."""
         out = {}
         for k in LEAVES:
-            per = 1 if k in ("zj", "ej", "pj", "h") else m.rows
+            per = 1 if k in HCU_LEAVES else m.rows
             v = jax.lax.dynamic_slice_in_dim(lv[k], at * per, hb * per, 0)
             out[k] = v[:, :m.cols] if k in ("z", "e", "p", "t") else v
         return out
@@ -395,14 +429,15 @@ def block_size(n: int, m: Model, budget_bytes: float = 2.5e8) -> int:
     return hb
 
 
-def compare_states(m: Model, pieces, ref: State) -> Errors:
+def compare_states(m: Model, pieces) -> Errors:
     """Largest per-field error of the system's flushed state against the
-    reference's. `pieces` yields (first HCU, leaves): the system's raw
-    state per device, on the reference's device, keyed as LEAVES and laid
-    out as the reference's ((n*R, C), (n*R,) and (n, C))."""
+    reference's. `pieces` yields (leaves, ref, first): the system's raw
+    state of n HCUs, keyed as LEAVES and laid out as the reference's
+    ((n*R, C), (n*R,) and (n, C)), the reference `State` of a share on the
+    same device, and the index in that share of the first of the n HCUs."""
     d = np.zeros(len(FIELDS))
     s = np.zeros(len(FIELDS))
-    for off, lv in pieces:
+    for lv, ref, off in pieces:
         n = lv["zj"].shape[0]
         hb = block_size(n, m)
         outs = [block_errors(lv, ref, s0, off + s0, m=m, hb=hb)
@@ -411,7 +446,7 @@ def compare_states(m: Model, pieces, ref: State) -> Errors:
             bd = np.asarray(bd)
             d = np.where(np.isnan(bd) | (bd > d), bd, d)
             s = np.maximum(s, np.asarray(bs))
-        del lv, outs
+        del lv, ref, outs
     d = np.where(np.isnan(d), np.inf, d)
     return Errors(dict(zip(FIELDS, map(float, d))),
                   dict(zip(FIELDS, map(float, s))))

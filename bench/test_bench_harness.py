@@ -49,6 +49,14 @@ def test_exposed_collective_and_busy():
         == 3 + 4
     assert xtrace.base_name("%fused_row_update_kernel_call.12") == \
         "fused_row_update_kernel_call"
+    # inside a scan: the `while` nests every op of the chunk, the exchange
+    # too, and does not hide it
+    scan = [("while.2", 0, 100), ("fusion.1", 10, 30),
+            ("%all_to_all.3 = s32[4,1,4096] all-to-all(...)", 30, 34),
+            ("conditional", 40, 90),
+            ("fused_col_update_kernel_call", 41, 80), ("copy", 80, 90)]
+    assert xtrace.containers(scan) == {0, 3}
+    assert xtrace.exposed_collective_ns(scan) == 4
 
 
 def test_idle_gaps_named_by_host_span():

@@ -16,9 +16,9 @@ from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OP_LINE = "XLA Ops"
-COLLECTIVE = re.compile(
-    r"^%?(all-to-all|all-gather|all-reduce|reduce-scatter|collective-permute"
-    r"|send|recv)")
+COLLECTIVE = re.compile(           # the TPU names an op `all_to_all.22`
+    r"^%?(all[-_]to[-_]all|all[-_]gather|all[-_]reduce|reduce[-_]scatter"
+    r"|collective[-_]permute|send|recv)")
 
 
 class Trace:
@@ -138,10 +138,30 @@ def time_containing(events, part: str) -> int:
     return sum(e - s for n, s, e in events if part in base_name(n))
 
 
+def containers(events) -> set:
+    """Indices into `events` of those that nest another event whole (a
+    scan's `while` nests its body's ops, a cond's `conditional` its
+    branch's)."""
+    out, stack = set(), []         # stack: indices, by start
+    for i in sorted(range(len(events)),
+                    key=lambda i: (events[i][1], -events[i][2])):
+        _, s, e = events[i]
+        while stack and events[stack[-1]][2] <= s:
+            stack.pop()
+        if stack and e <= events[stack[-1]][2]:
+            out.add(stack[-1])
+        stack.append(i)
+    return out
+
+
 def exposed_collective_ns(events) -> int:
-    """Time in which a collective runs on the device and no other op does."""
+    """Time in which a collective runs on the device and no other op does;
+    an op that nests others (the scan's `while` around the whole chunk)
+    does not count as running beside it."""
+    nest = containers(events)
     coll = union((s, e) for n, s, e in events if COLLECTIVE.match(n))
-    other = [(s, e) for n, s, e in events if not COLLECTIVE.match(n)]
+    other = [(s, e) for i, (n, s, e) in enumerate(events)
+             if i not in nest and not COLLECTIVE.match(n)]
     return length(subtract(coll, other))
 
 
